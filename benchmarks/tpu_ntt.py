@@ -81,7 +81,8 @@ def run(emit):
 
 
 def correctness_check(emit):
-    """Tiny interpret-mode run to prove the benchmarked kernel is the real one."""
+    """Tiny run to prove the benchmarked kernel is the real one; the row
+    names the platform it ran on and whether the kernel was interpreted."""
     ctx = make_context(mm.DEFAULT_Q, 4096)
     x = np.random.default_rng(0).integers(
         0, mm.DEFAULT_Q, (2, 4096)).astype(np.uint32)
@@ -89,12 +90,16 @@ def correctness_check(emit):
     if not pallas.available():
         emit("tpu_ntt/kernel_check", 0.0, "skipped=jax-unavailable")
         return
-    from repro.kernels.ntt import ntt_pallas
+    import jax
+
+    from repro.kernels.ntt import ntt_pallas, resolve_interpret
 
     got = np.asarray(ntt_pallas(x, ctx, forward=True, tile=1024))
     exp = get_backend("reference").ntt(x, forward=True)
     assert np.array_equal(got, exp)
-    emit("tpu_ntt/kernel_check", 0.0, "interpret-mode==oracle")
+    mode = "interpret" if resolve_interpret(None) else "compiled"
+    emit("tpu_ntt/kernel_check", 0.0,
+         f"platform={jax.devices()[0].platform};{mode};pallas==reference")
 
 
 def backend_rows(emit, quick: bool = True, cfg: PimConfig | None = None):

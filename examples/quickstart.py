@@ -11,7 +11,7 @@ import numpy as np
 from repro.core import modmath as mm
 from repro.core import ntt
 from repro.core.pim_config import PimConfig
-from repro.kernels.ntt import ntt_pallas
+from repro.kernels.ntt import ntt_pallas, resolve_interpret
 from repro.pimsys import NttOp, PimSession
 
 N = 2048
@@ -40,8 +40,8 @@ def main():
     batch = np.stack([poly] * 8)
     got_tpu = np.asarray(ntt_pallas(batch, ctx, forward=True))
     assert np.array_equal(got_tpu[0], ref), "Pallas kernel mismatch!"
-    print(f"[tpu] N={N} x batch=8: Pallas row-centric kernel == oracle "
-          f"(interpret mode; lowers to TPU via the same code path)")
+    mode = "interpreted on CPU" if resolve_interpret(None) else "compiled on TPU"
+    print(f"[tpu] N={N} x batch=8: Pallas row-centric kernel == oracle ({mode})")
 
     # polynomial multiplication (the FHE use-case, eq. 1)
     b = rng.integers(0, Q, N).astype(np.uint32)

@@ -9,9 +9,11 @@ benchmarked through one harness (`benchmarks/tpu_ntt.py`):
              `FunctionalBank` via `mapping.pim_ntt`, with the modeled
              `BankTimer` latency available for table3-style PIM-vs-TPU
              rows.
-  pallas     the jax/pallas TPU kernel lane (`kernels.ntt.ntt_pallas`),
-             interpret-mode on CPU; gated on jax being importable so
-             the package (and this module) stay usable without it.
+  pallas     the jax/pallas TPU kernel lane (`kernels.ntt.ntt_pallas`):
+             compiled on a TPU, interpreted on the CPU backend (each
+             tile a (rows, 128) slab; see `kernels/ntt.py`); gated on
+             jax being importable so the package (and this module)
+             stay usable without it.
 
 Contract (shared by all three): uint32 arrays over the last axis,
 `forward=True` is natural in -> bit-reversed out, `forward=False` is
@@ -128,7 +130,9 @@ class PimSimBackend(NttBackend):
 
 
 class PallasBackend(NttBackend):
-    """The jax/pallas TPU kernel lane; interpret mode off-TPU."""
+    """The jax/pallas TPU kernel lane: compiled on a TPU, interpreted on
+    the CPU backend, and an error on any other (`resolve_interpret`);
+    `interpret` overrides the choice."""
 
     name = "pallas"
     summary = "jax/pallas tiled kernel (kernels.ntt.ntt_pallas)"

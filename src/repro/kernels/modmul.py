@@ -15,12 +15,9 @@ from jax.experimental import pallas as pl
 
 from repro.core import modmath as mm
 from repro.core.ntt import NttContext
+from repro.kernels.ntt import resolve_interpret
 
 DEFAULT_BLOCK = 16384  # words = 64 KiB per operand tile
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _modmul_kernel(a_ref, b_ref, o_ref, *, q, qprime, r2):
@@ -32,7 +29,7 @@ def _modmul_kernel(a_ref, b_ref, o_ref, *, q, qprime, r2):
 @functools.partial(jax.jit, static_argnames=("ctx", "block", "interpret"))
 def modmul_pallas(a, b, ctx: NttContext, block: int | None = None, interpret: bool | None = None):
     """Element-wise a*b mod q over arbitrary (batch..., n) uint32 arrays."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     shape = a.shape
     assert a.shape == b.shape
     flat_a = a.reshape(-1)

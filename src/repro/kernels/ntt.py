@@ -1,6 +1,6 @@
 """Row-centric NTT as Pallas TPU kernels.
 
-The PIM -> TPU mapping (DESIGN.md §2):
+The PIM -> TPU mapping:
 
   regime A (intra-atom + intra-row)  -> `_ntt_tile_kernel`: ALL stages with
       stride < T fused over a single VMEM-resident tile; one HBM read +
@@ -15,16 +15,29 @@ The PIM -> TPU mapping (DESIGN.md §2):
       compute — the Nb-buffer pipelining idea; each HBM tile is touched
       exactly once (read+write) per stage — the activation-grouping idea.
   bank-level parallelism             -> the batch grid axis (FHE runs many
-      independent NTTs; see ops.ntt / shard_map batching).
+      independent NTTs in one call; see ops.ntt).
 
-Twiddles are precomputed tables fed through VMEM and shared across the
-batch (changed assumption #1 in DESIGN.md: the paper's on-the-fly
-(w0, r_w) generation saves DRAM bandwidth; on TPU a serial recurrence
-would idle the VPU, and the tables cost O(T) VMEM).
+Layout.  A tile of T words is a (T/128, 128) slab: vector rows of 128
+lanes, so no reshape ever splits the lane axis.  Inside a tile, a stage
+of stride s pairs element i with element i ^ s:
+  * s < 128: lane l pairs with lane l ^ s of the same row;
+  * s >= 128: row r pairs with row r ^ (s/128), lane by lane.
+Both are done with one rotation each way (`pltpu.roll`) and a select on
+the stride bit of the position, so every butterfly is a whole-slab
+vector op.  Twiddles are laid out one per element on the host: the
+stage's twiddle at the upper ("v") element of each pair and 1 at the
+lower, so one Shoup multiply over the slab scales exactly the v half.
+The inter-tile stages keep a tile's (rows, 128) slab as the block's last
+two dims and read their one twiddle per butterfly group from SMEM
+(scalar prefetch), indexed by the grid.
+
+Twiddles are precomputed tables shared across the batch (the paper's
+on-the-fly (w0, r_w) generation saves DRAM bandwidth; on TPU a serial
+recurrence would idle the VPU, and the tables cost O(T) VMEM).
 
 All arithmetic is uint32 with 16-bit-limb emulation of 32x32->64
 products (TPUs have no 64-bit integer multiply); q < 2^31.  Kernels run
-with interpret=True on CPU and compile for TPU through the same path.
+interpreted on the CPU backend and compiled on the TPU (`resolve_interpret`).
 """
 from __future__ import annotations
 
@@ -34,39 +47,61 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import modmath as mm
-from repro.core.ntt import NttContext, Stage, forward_stages, inverse_stages
+from repro.core.ntt import NttContext, forward_stages, inverse_stages
 
-DEFAULT_TILE = 8192  # words: 32 KiB data/tile + 32 KiB twiddles << VMEM
+LANES = 128  # words per vector row: the minor dim of every block
+DEFAULT_TILE = 8192  # words: a (64, 128) slab, 32 KiB per row of the batch
 DEFAULT_BATCH_BLOCK = 8
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Interpret mode on the CPU backend, compiled on the TPU.
+
+    An explicit `interpret` wins.  Any other backend raises instead of
+    quietly interpreting, so a run that lost its TPU fails loudly.
+    """
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels compile for 'tpu' or interpret on 'cpu'; "
+        f"the default backend is {backend!r}"
+    )
 
 
 # ---------------------------------------------------------------------------
-# stage micro-kernel — one butterfly stage over the last axis of (B, L)
+# stage micro-kernel — one butterfly stage over a (bb, rows, 128) slab
 # ---------------------------------------------------------------------------
 
 
-def _stage_block(x, tw, tw_sh, stage: Stage, q: int):
-    b = x.shape[0]
-    n = x.shape[-1]
-    xr = x.reshape(b, stage.blocks, 2, stage.stride)
-    u = xr[:, :, 0, :]
-    v = xr[:, :, 1, :]
-    w = tw.reshape(1, stage.blocks, 1)
-    w_sh = tw_sh.reshape(1, stage.blocks, 1)
-    if stage.gs:
-        out0 = mm.addmod_u32(u, v, q)
-        out1 = mm.shoup_mulmod_u32(mm.submod_u32(u, v, q), w, w_sh, q)
-    else:
-        wv = mm.shoup_mulmod_u32(v, w, w_sh, q)
-        out0 = mm.addmod_u32(u, wv, q)
-        out1 = mm.submod_u32(u, wv, q)
-    return jnp.stack([out0, out1], axis=2).reshape(b, n)
+def _butterfly(x, w, w_sh, stride: int, axis: int, gs: bool, q: int):
+    """One stage: element i pairs with i ^ stride along `axis`.
+
+    `w` holds the pair's twiddle at the upper element and 1 at the lower.
+    CT (forward): (u, v) -> (u + w v, u - w v).
+    GS (inverse): (u, v) -> (u + v, (u - v) w).
+    """
+    size = x.shape[axis]
+    upper = (jax.lax.broadcasted_iota(jnp.int32, x.shape, axis) & stride) != 0
+
+    def from_lower(a):  # a[i - stride]: the u partner of an upper element
+        return pltpu.roll(a, stride, axis)
+
+    def from_upper(a):  # a[i + stride]: the v partner of a lower element
+        return pltpu.roll(a, size - stride, axis)
+
+    if gs:
+        z = jnp.where(upper, mm.submod_u32(from_lower(x), x, q), mm.addmod_u32(x, from_upper(x), q))
+        return mm.shoup_mulmod_u32(z, w, w_sh, q)
+    y = mm.shoup_mulmod_u32(x, w, w_sh, q)  # u at lower, w*v at upper
+    return jnp.where(upper, mm.submod_u32(from_lower(y), y, q), mm.addmod_u32(y, from_upper(y), q))
 
 
 # ---------------------------------------------------------------------------
@@ -74,49 +109,42 @@ def _stage_block(x, tw, tw_sh, stage: Stage, q: int):
 # ---------------------------------------------------------------------------
 
 
-def _ntt_tile_kernel(x_ref, tw_ref, twsh_ref, o_ref, *, stages, q, scale):
-    x = x_ref[...]
-    if x.ndim == 3:  # (bb, 1, tile) block from the tiled path
-        x = x[:, 0, :]
-    tw_all = tw_ref[...].reshape(-1)
-    twsh_all = twsh_ref[...].reshape(-1)
-    for st in stages:
-        tw = jax.lax.slice(tw_all, (st.tw_lo,), (st.tw_lo + st.blocks,))
-        tw_sh = jax.lax.slice(twsh_all, (st.tw_lo,), (st.tw_lo + st.blocks,))
-        x = _stage_block(x, tw, tw_sh, st, q)
+def _ntt_tile_kernel(x_ref, tw_ref, o_ref, *, strides, gs, q, scale):
+    x = x_ref[...]  # (bb, rows, 128)
+    for k, s in enumerate(strides):
+        w, w_sh = tw_ref[0, k], tw_ref[1, k]  # (rows, 128) each
+        if s < LANES:
+            x = _butterfly(x, w, w_sh, s, 2, gs, q)
+        else:
+            x = _butterfly(x, w, w_sh, s // LANES, 1, gs, q)
     if scale is not None:
         n_inv, n_inv_sh = scale
         x = mm.shoup_mulmod_u32(x, np.uint32(n_inv), np.uint32(n_inv_sh), q)
-    o_ref[...] = x.reshape(o_ref.shape)
+    o_ref[...] = x
 
 
-def _pack_tile_stages(ctx: NttContext, n: int, tile: int, forward: bool):
-    """Per-tile packed twiddle tables + stage plans with packed offsets.
+def _tile_tables(ctx: NttContext, tile: int, forward: bool):
+    """Per-element twiddles of the stages with stride < tile.
 
-    For tile j (global offset o = j*tile) the stage with stride t uses
-    table[h + o/(2t) : ... + tile/(2t)] (h = n/(2t)) — a contiguous slice,
-    so all of tile j's stage twiddles concatenate into row j of a
-    (n_tiles, tile) array; one BlockSpec row feeds the fused kernel.
+    Returns (strides, tables) with tables of shape
+    (n_tiles, 2, n_stages, tile/128, 128): [w, shoup(w)] for each stage,
+    w at the upper element of each pair and 1 at the lower.
     """
+    n, q = ctx.n, ctx.q
     table = ctx.psi_brv if forward else ctx.psi_inv_brv
     table_sh = ctx.psi_brv_shoup if forward else ctx.psi_inv_brv_shoup
-    plan_full = forward_stages(n) if forward else inverse_stages(n)
-    stages = [st for st in plan_full if st.stride < tile]
-    n_tiles = n // tile
-    packed = np.zeros((n_tiles, tile), np.uint32)
-    packed_sh = np.zeros((n_tiles, tile), np.uint32)
-    local_stages = []
-    cursor = 0
-    for st in stages:
-        h = n // (2 * st.stride)
-        per_tile = tile // (2 * st.stride)
-        for j in range(n_tiles):
-            lo = h + (j * tile) // (2 * st.stride)
-            packed[j, cursor : cursor + per_tile] = table[lo : lo + per_tile]
-            packed_sh[j, cursor : cursor + per_tile] = table_sh[lo : lo + per_tile]
-        local_stages.append(Stage(blocks=per_tile, stride=st.stride, tw_lo=cursor, gs=st.gs))
-        cursor += per_tile
-    return packed, packed_sh, local_stages
+    plan = forward_stages(n) if forward else inverse_stages(n)
+    stages = [st for st in plan if st.stride < tile]
+    pos = np.arange(n)
+    w = np.ones((len(stages), n), np.uint32)
+    w_sh = np.full((len(stages), n), mm.shoup(1, q), np.uint32)
+    for k, st in enumerate(stages):
+        upper = (pos & st.stride) != 0
+        idx = st.tw_lo + pos[upper] // (2 * st.stride)
+        w[k, upper] = table[idx]
+        w_sh[k, upper] = table_sh[idx]
+    tabs = np.stack([w, w_sh]).reshape(2, len(stages), n // tile, tile // LANES, LANES)
+    return tuple(st.stride for st in stages), np.ascontiguousarray(tabs.transpose(2, 0, 1, 3, 4))
 
 
 # ---------------------------------------------------------------------------
@@ -124,12 +152,13 @@ def _pack_tile_stages(ctx: NttContext, n: int, tile: int, forward: bool):
 # ---------------------------------------------------------------------------
 
 
-def _ntt_pair_kernel(x_ref, tw_ref, twsh_ref, o_ref, *, gs, q):
-    # block shape (bb, 1, 2, 1, tile): dim 2 separates the butterfly halves
-    u = x_ref[:, 0, 0, 0, :]
-    v = x_ref[:, 0, 1, 0, :]
-    w = tw_ref[0]
-    w_sh = twsh_ref[0]
+def _ntt_pair_kernel(tw_ref, x_ref, o_ref, *, gs, q):
+    # block (bb, 2, rows, 128): dim 1 separates the butterfly halves;
+    # tw_ref is the (2, n_groups) [w, shoup(w)] table in SMEM.
+    g = pl.program_id(1)
+    w, w_sh = tw_ref[0, g], tw_ref[1, g]
+    u = x_ref[:, 0]
+    v = x_ref[:, 1]
     if gs:
         nu = mm.addmod_u32(u, v, q)
         nv = mm.shoup_mulmod_u32(mm.submod_u32(u, v, q), w, w_sh, q)
@@ -137,8 +166,8 @@ def _ntt_pair_kernel(x_ref, tw_ref, twsh_ref, o_ref, *, gs, q):
         wv = mm.shoup_mulmod_u32(v, w, w_sh, q)
         nu = mm.addmod_u32(u, wv, q)
         nv = mm.submod_u32(u, wv, q)
-    o_ref[:, 0, 0, 0, :] = nu
-    o_ref[:, 0, 1, 0, :] = nv
+    o_ref[:, 0] = nu
+    o_ref[:, 1] = nv
 
 
 # ---------------------------------------------------------------------------
@@ -162,115 +191,96 @@ def ntt_pallas(
     forward: natural order in -> bit-reversed out (CT butterflies).
     inverse: bit-reversed in -> natural out, scaled by 1/N (GS).
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     n = ctx.n
-    assert x.shape[-1] == n, (x.shape, n)
+    if x.shape[-1] != n:
+        raise ValueError(f"last axis is {x.shape[-1]}, the context is for n={n}")
+    tile = min(tile or DEFAULT_TILE, n)
+    if tile % LANES or tile & (tile - 1):
+        raise ValueError(
+            f"tile must be a power of two and a multiple of {LANES} words, got "
+            f"{tile}: each tile is laid out as rows of {LANES} lanes"
+        )
     squeeze = x.ndim == 1
     if squeeze:
         x = x[None, :]
     batch = x.shape[0]
-    tile = min(tile or DEFAULT_TILE, n)
     bb = min(batch_block or DEFAULT_BATCH_BLOCK, batch)
     pad = (-batch) % bb
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
-    scale = (ctx.n_inv, ctx.n_inv_shoup) if not forward else None
-
-    if tile >= n:
-        out = _fused_full(x, ctx, forward, bb, interpret, scale)
-    else:
-        out = _two_regime(x, ctx, forward, tile, bb, interpret, scale)
+    out = _two_regime(x, ctx, forward, tile, bb, interpret)
     if pad:
-        out = out[: x.shape[0] - pad]
+        out = out[:batch]
     return out[0] if squeeze else out
 
 
-def _fused_full(x, ctx, forward, bb, interpret, scale):
-    """n <= tile: whole transform VMEM-resident (regime A only)."""
-    n = ctx.n
+def _two_regime(x, ctx, forward, tile, bb, interpret):
+    """Fused intra-tile pass + one in-place pass per inter-tile stage.
+
+    With tile == n there are no inter-tile stages and the whole transform,
+    1/N scale included, is one fused pass.
+    """
+    n, q = ctx.n, ctx.q
+    batch = x.shape[0]
+    n_tiles = n // tile
+    rows = tile // LANES
     table = ctx.psi_brv if forward else ctx.psi_inv_brv
     table_sh = ctx.psi_brv_shoup if forward else ctx.psi_inv_brv_shoup
     plan = forward_stages(n) if forward else inverse_stages(n)
-    batch = x.shape[0]
-    kernel = functools.partial(_ntt_tile_kernel, stages=plan, q=ctx.q, scale=scale)
-    return pl.pallas_call(
-        kernel,
-        grid=(batch // bb,),
-        in_specs=[
-            pl.BlockSpec((bb, n), lambda i: (i, 0)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bb, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, jnp.uint32),
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(x, jnp.asarray(table), jnp.asarray(table_sh))
+    inter = [st for st in plan if st.stride >= tile]
+    scale = None if forward else (ctx.n_inv, ctx.n_inv_shoup)
 
-
-def _two_regime(x, ctx, forward, tile, bb, interpret, scale):
-    """n > tile: fused intra-tile pass + one in-place pass per inter stage."""
-    n = ctx.n
-    batch = x.shape[0]
-    n_tiles = n // tile
-    table = ctx.psi_brv if forward else ctx.psi_inv_brv
-    table_sh = ctx.psi_brv_shoup if forward else ctx.psi_inv_brv_shoup
-    plan_full = forward_stages(n) if forward else inverse_stages(n)
-    inter = [st for st in plan_full if st.stride >= tile]
-    packed, packed_sh, local_stages = _pack_tile_stages(ctx, n, tile, forward)
-
-    def run_intra(x):
-        kernel = functools.partial(_ntt_tile_kernel, stages=local_stages, q=ctx.q, scale=None)
-        xr = x.reshape(batch, n_tiles, tile)
+    def run_intra(x, scale):
+        strides, tabs = _tile_tables(ctx, tile, forward)
+        kernel = functools.partial(_ntt_tile_kernel, strides=strides, gs=not forward, q=q, scale=scale)
+        xr = x.reshape(batch, n_tiles, rows, LANES)
+        # tiles outermost: a tile's twiddle block is fetched once, not per batch block
         out = pl.pallas_call(
             kernel,
-            grid=(batch // bb, n_tiles),
+            grid=(n_tiles, batch // bb),
             in_specs=[
-                pl.BlockSpec((bb, 1, tile), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((1, tile), lambda i, j: (j, 0)),
-                pl.BlockSpec((1, tile), lambda i, j: (j, 0)),
+                pl.BlockSpec((bb, None, rows, LANES), lambda j, i: (i, j, 0, 0)),
+                pl.BlockSpec((None, 2, len(strides), rows, LANES), lambda j, i: (j, 0, 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((bb, 1, tile), lambda i, j: (i, j, 0)),
+            out_specs=pl.BlockSpec((bb, None, rows, LANES), lambda j, i: (i, j, 0, 0)),
             out_shape=jax.ShapeDtypeStruct(xr.shape, jnp.uint32),
             input_output_aliases={0: 0},
             interpret=interpret,
-        )(xr, jnp.asarray(packed), jnp.asarray(packed_sh))
+        )(xr, jnp.asarray(tabs))
         return out.reshape(batch, n)
 
-    def run_inter_stage(x, st: Stage):
+    def run_inter_stage(x, st):
         st_tiles = st.stride // tile
         n_groups = n_tiles // (2 * st_tiles)
         h = n // (2 * st.stride)
         # twiddle depends only on the group index g: u-tile offset
         # = (g*2*st_tiles + s)*tile, and (offset)/(2*stride) = g.
-        tw = np.asarray(table)[h : h + n_groups].astype(np.uint32)
-        tw_sh = np.asarray(table_sh)[h : h + n_groups].astype(np.uint32)
-        x5 = x.reshape(batch, n_groups, 2, st_tiles, tile)
-        kernel = functools.partial(_ntt_pair_kernel, gs=st.gs, q=ctx.q)
+        tw = np.stack([table[h : h + n_groups], table_sh[h : h + n_groups]]).astype(np.uint32)
+        x6 = x.reshape(batch, n_groups, 2, st_tiles, rows, LANES)
+        block = pl.BlockSpec((bb, None, 2, None, rows, LANES), lambda i, g, s, tw: (i, g, 0, s, 0, 0))
         out = pl.pallas_call(
-            kernel,
-            grid=(batch // bb, n_groups, st_tiles),
-            in_specs=[
-                pl.BlockSpec((bb, 1, 2, 1, tile), lambda i, g, s: (i, g, 0, s, 0)),
-                pl.BlockSpec((1,), lambda i, g, s: (g,)),
-                pl.BlockSpec((1,), lambda i, g, s: (g,)),
-            ],
-            out_specs=pl.BlockSpec((bb, 1, 2, 1, tile), lambda i, g, s: (i, g, 0, s, 0)),
-            out_shape=jax.ShapeDtypeStruct(x5.shape, jnp.uint32),
-            input_output_aliases={0: 0},
+            functools.partial(_ntt_pair_kernel, gs=st.gs, q=q),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(batch // bb, n_groups, st_tiles),
+                in_specs=[block],
+                out_specs=block,
+            ),
+            out_shape=jax.ShapeDtypeStruct(x6.shape, jnp.uint32),
+            input_output_aliases={1: 0},
             interpret=interpret,
-        )(x5, jnp.asarray(tw), jnp.asarray(tw_sh))
+        )(jnp.asarray(tw), x6)
         return out.reshape(batch, n)
 
+    if not inter:
+        return run_intra(x, scale)
     if forward:
         for st in inter:  # large strides first
             x = run_inter_stage(x, st)
-        x = run_intra(x)
-    else:
-        x = run_intra(x)
-        for st in inter:
-            x = run_inter_stage(x, st)
-    if scale is not None:
-        n_inv, n_inv_sh = scale
-        x = mm.shoup_mulmod_u32(x, np.uint32(n_inv), np.uint32(n_inv_sh), ctx.q)
-    return x
+        return run_intra(x, None)
+    x = run_intra(x, None)
+    for st in inter:
+        x = run_inter_stage(x, st)
+    n_inv, n_inv_sh = scale
+    return mm.shoup_mulmod_u32(x, np.uint32(n_inv), np.uint32(n_inv_sh), q)

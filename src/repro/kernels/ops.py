@@ -10,8 +10,8 @@
                        integer convolution, and un-lift
 
 Batching across independent transforms == the paper's bank-level
-parallelism; across devices, shard the batch axis of these ops with
-pjit/shard_map (they are purely element-parallel in batch).
+parallelism (the batch grid axis of each kernel).  The ops run on one
+device; nothing here shards them across chips.
 """
 from __future__ import annotations
 

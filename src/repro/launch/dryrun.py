@@ -198,10 +198,7 @@ def _depth_variant(cfg, n_reps: int):
 
 
 def cost_dict(cost) -> dict:
-    """Normalize Compiled.cost_analysis(): older jax returns a one-element
-    list of dicts (per device), newer jax the dict itself."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
+    """Compiled.cost_analysis() as a dict ({} when the backend has none)."""
     return cost or {}
 
 

@@ -3,7 +3,7 @@
 The only sequential recurrence in the evaluator is the speculative bus
 chain (everything else is elementwise / exact-max gathers), so the jax
 backend swaps exactly that seam: a jitted `jax.lax.scan` in float64
-(x64 scoped via `jax.experimental.enable_x64` so importing the backend
+(x64 scoped via the `jax.enable_x64(True)` context so importing the backend
 never mutates process-global jax config).
 `lax.scan` is a strict left fold — the same add-by-add semantics as
 `np.cumsum` — so results remain bit-identical to the interpreted
@@ -60,7 +60,7 @@ def jax_chain(b0: float, pn_blk: np.ndarray, n: int,
     # x64 is scoped, never flipped globally: importing (or using) this
     # backend must not change dtype defaults for unrelated jax code in
     # the same process (jit re-traces under the scoped config)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         vals = np.asarray(_scan_chain(jnp.float64(b0), jnp.asarray(inc)))
     out = np.empty(1 + 2 * K * n)
     out[0] = b0

@@ -1,0 +1,71 @@
+"""Compile the kernel lane for a described TPU v5e at FHE ring sizes.
+
+Nothing runs: each case lowers and compiles, ahead of time, for one chip
+of a `v5e:2x2` topology that JAX describes without the hardware, so
+Mosaic refuses here what it would refuse on the chip (unaligned blocks,
+unsupported shape casts, too much VMEM).  The topology is described in a
+fixture, never at import: only the worker that runs this file loads the
+TPU compiler.
+"""
+import os
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from repro.core import modmath as mm  # noqa: E402
+from repro.core.ntt import make_context  # noqa: E402
+from repro.kernels import ops  # noqa: E402
+from repro.kernels.modmul import modmul_pallas  # noqa: E402
+from repro.kernels.ntt import ntt_pallas  # noqa: E402
+
+BATCH = 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache off around these
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, n_args, n, sharding):
+    arg = jax.ShapeDtypeStruct((BATCH, n), jnp.uint32, sharding=sharding)
+    compiled = jax.jit(fn).lower(*([arg] * n_args)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == BATCH * n * 4
+    return compiled
+
+
+@pytest.mark.parametrize("forward", [True, False], ids=["forward", "inverse"])
+@pytest.mark.parametrize("log_n", [8, 12, 14, 16, 17])
+def test_ntt_compiles_for_v5e(one_chip, log_n, forward):
+    n = 1 << log_n
+    ctx = make_context(mm.DEFAULT_Q, n)
+    _compile(lambda x: ntt_pallas(x, ctx, forward=forward, interpret=False), 1, n, one_chip)
+
+
+def test_modmul_compiles_for_v5e(one_chip):
+    ctx = make_context(mm.DEFAULT_Q, 1 << 16)
+    _compile(lambda a, b: modmul_pallas(a, b, ctx, interpret=False), 2, 1 << 16, one_chip)
+
+
+def test_polymul_compiles_for_v5e(one_chip):
+    ctx = make_context(mm.DEFAULT_Q, 1 << 16)
+    _compile(lambda a, b: ops.polymul_ntt(a, b, ctx, interpret=False), 2, 1 << 16, one_chip)

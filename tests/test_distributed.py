@@ -146,11 +146,8 @@ def test_compressed_psum_small_mesh():
         from repro.distributed.compression import compressed_psum
         from repro.launch.mesh import make_host_mesh
 
-        kw = {}
-        at = getattr(jax.sharding, 'AxisType', None)  # absent pre-0.5 jax
-        if at is not None:
-            kw['axis_types'] = (at.Auto,) * 2
-        mesh = jax.make_mesh((2, 4), ('pod', 'data'), **kw)
+        mesh = jax.make_mesh((2, 4), ('pod', 'data'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 64)), jnp.float32)
 
         def f(x):

@@ -1,7 +1,8 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps + properties.
 
-All kernels run in interpret mode on CPU (the TPU lowering shares the
-same code path; see also the dry-run which .lower().compile()s them)."""
+All kernels run in interpret mode on the CPU backend.  The same kernels
+are compiled for a described TPU v5e in `tests/test_chip_compile.py`, and
+run on the chip by `chip_smoke.py`."""
 import numpy as np
 import pytest
 from hypo import given, settings, st
@@ -55,6 +56,27 @@ def test_ntt_kernel_roundtrip(n, tile):
     f = ntt_pallas(x, ctx, forward=True, tile=tile)
     back = np.asarray(ntt_pallas(f, ctx, forward=False, tile=tile))
     np.testing.assert_array_equal(back, x)
+
+
+@pytest.mark.parametrize("n,tile", [(1024, 192), (1024, 384), (64, None)])
+def test_ntt_kernel_rejects_partial_lane_rows(n, tile):
+    ctx = make_context(Q, n)
+    with pytest.raises(ValueError, match="multiple of 128 words"):
+        ntt_pallas(rand((1, n)), ctx, tile=tile)
+
+
+def test_resolve_interpret_by_backend(monkeypatch):
+    import jax
+
+    from repro.kernels.ntt import resolve_interpret
+
+    assert resolve_interpret(None) is True  # the tests run on the CPU backend
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        resolve_interpret(None)
 
 
 def test_ntt_kernel_1d_input():
