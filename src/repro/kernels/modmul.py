@@ -54,6 +54,7 @@ def modmul_pallas(a, b, ctx: NttContext, block: int | None = None, interpret: bo
         out_shape=jax.ShapeDtypeStruct(flat_a.shape, jnp.uint32),
         input_output_aliases={0: 0},
         interpret=interpret,
+        name="modmul",
     )(flat_a, flat_b)
     if pad:
         out = out[:n]

@@ -51,6 +51,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import modmath as mm
 from repro.core.ntt import NttContext, forward_stages, inverse_stages
+from repro.kernels import stats
 
 LANES = 128  # words per vector row: the minor dim of every block
 DEFAULT_TILE = 8192  # words: a (64, 128) slab, 32 KiB per row of the batch
@@ -190,29 +191,33 @@ def ntt_pallas(
 
     forward: natural order in -> bit-reversed out (CT butterflies).
     inverse: bit-reversed in -> natural out, scaled by 1/N (GS).
+    Its device ops carry the scope `lane.ntt` or `lane.intt`; the kernels
+    are named `ntt_tile_fwd`/`_inv` (the fused intra-tile pass) and
+    `ntt_stage_fwd`/`_inv` (one inter-tile stage).
     """
-    interpret = resolve_interpret(interpret)
-    n = ctx.n
-    if x.shape[-1] != n:
-        raise ValueError(f"last axis is {x.shape[-1]}, the context is for n={n}")
-    tile = min(tile or DEFAULT_TILE, n)
-    if tile % LANES or tile & (tile - 1):
-        raise ValueError(
-            f"tile must be a power of two and a multiple of {LANES} words, got "
-            f"{tile}: each tile is laid out as rows of {LANES} lanes"
-        )
-    squeeze = x.ndim == 1
-    if squeeze:
-        x = x[None, :]
-    batch = x.shape[0]
-    bb = min(batch_block or DEFAULT_BATCH_BLOCK, batch)
-    pad = (-batch) % bb
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-    out = _two_regime(x, ctx, forward, tile, bb, interpret)
-    if pad:
-        out = out[:batch]
-    return out[0] if squeeze else out
+    with stats.scope("ntt" if forward else "intt"):
+        interpret = resolve_interpret(interpret)
+        n = ctx.n
+        if x.shape[-1] != n:
+            raise ValueError(f"last axis is {x.shape[-1]}, the context is for n={n}")
+        tile = min(tile or DEFAULT_TILE, n)
+        if tile % LANES or tile & (tile - 1):
+            raise ValueError(
+                f"tile must be a power of two and a multiple of {LANES} words, got "
+                f"{tile}: each tile is laid out as rows of {LANES} lanes"
+            )
+        squeeze = x.ndim == 1
+        if squeeze:
+            x = x[None, :]
+        batch = x.shape[0]
+        bb = min(batch_block or DEFAULT_BATCH_BLOCK, batch)
+        pad = (-batch) % bb
+        if pad:
+            x = jnp.pad(x, ((0, pad), (0, 0)))
+        out = _two_regime(x, ctx, forward, tile, bb, interpret)
+        if pad:
+            out = out[:batch]
+        return out[0] if squeeze else out
 
 
 def _two_regime(x, ctx, forward, tile, bb, interpret):
@@ -230,6 +235,7 @@ def _two_regime(x, ctx, forward, tile, bb, interpret):
     plan = forward_stages(n) if forward else inverse_stages(n)
     inter = [st for st in plan if st.stride >= tile]
     scale = None if forward else (ctx.n_inv, ctx.n_inv_shoup)
+    way = "fwd" if forward else "inv"
 
     def run_intra(x, scale):
         strides, tabs = _tile_tables(ctx, tile, forward)
@@ -247,6 +253,7 @@ def _two_regime(x, ctx, forward, tile, bb, interpret):
             out_shape=jax.ShapeDtypeStruct(xr.shape, jnp.uint32),
             input_output_aliases={0: 0},
             interpret=interpret,
+            name=f"ntt_tile_{way}",
         )(xr, jnp.asarray(tabs))
         return out.reshape(batch, n)
 
@@ -270,6 +277,7 @@ def _two_regime(x, ctx, forward, tile, bb, interpret):
             out_shape=jax.ShapeDtypeStruct(x6.shape, jnp.uint32),
             input_output_aliases={1: 0},
             interpret=interpret,
+            name=f"ntt_stage_{way}",
         )(jnp.asarray(tw), x6)
         return out.reshape(batch, n)
 

@@ -8,6 +8,7 @@ fixture, never at import: only the worker that runs this file loads the
 TPU compiler.
 """
 import os
+import re
 
 import pytest
 
@@ -69,3 +70,50 @@ def test_modmul_compiles_for_v5e(one_chip):
 def test_polymul_compiles_for_v5e(one_chip):
     ctx = make_context(mm.DEFAULT_Q, 1 << 16)
     _compile(lambda a, b: ops.polymul_ntt(a, b, ctx, interpret=False), 2, 1 << 16, one_chip)
+
+
+#: The lane's kernels by the names its `pallas_call`s give them.
+KERNEL_NAME = re.compile(r"^(ntt_tile_(fwd|inv)|ntt_stage_(fwd|inv)|modmul)\.\d+$")
+
+
+def _lane_names(compiled, scoped: bool) -> set:
+    """The names of the compiled program's kernels; every one must be a lane
+    kernel, and with `scoped` every op that JAX traced carries a `lane.` scope."""
+    kernels = set()
+    for line in compiled.as_text().splitlines():
+        name = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line)
+        if name and 'custom_call_target="tpu_custom_call"' in line:
+            assert KERNEL_NAME.match(name[1]), line[:120]
+            kernels.add(name[1].rsplit(".", 1)[0])
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        # constants and parameters carry no op of their own: their op_name
+        # ends at a jit(...) or has no path at all
+        if scoped and op_name and "/" in op_name[1] and not op_name[1].rsplit("/", 1)[1].startswith("jit("):
+            assert "/lane." in op_name[1], line[:160]
+    return kernels
+
+
+@pytest.mark.parametrize("forward", [True, False], ids=["forward", "inverse"])
+@pytest.mark.parametrize("log_n", [8, 16])
+def test_ntt_kernels_are_named_and_scoped_for_v5e(one_chip, log_n, forward):
+    n = 1 << log_n
+    ctx = make_context(mm.DEFAULT_Q, n)
+    compiled = _compile(lambda x: ntt_pallas(x, ctx, forward=forward, interpret=False), 1, n, one_chip)
+    way = "fwd" if forward else "inv"
+    want = {f"ntt_tile_{way}"} | ({f"ntt_stage_{way}"} if log_n > 13 else set())
+    assert _lane_names(compiled, scoped=True) == want
+
+
+def test_modmul_kernel_is_named_for_v5e(one_chip):
+    ctx = make_context(mm.DEFAULT_Q, 1 << 16)
+    compiled = _compile(lambda a, b: modmul_pallas(a, b, ctx, interpret=False), 2, 1 << 16, one_chip)
+    assert _lane_names(compiled, scoped=False) == {"modmul"}
+
+
+def test_polymul_kernels_are_named_and_scoped_for_v5e(one_chip):
+    ctx = make_context(mm.DEFAULT_Q, 1 << 16)
+    compiled = _compile(lambda a, b: ops.polymul_ntt(a, b, ctx, interpret=False), 2, 1 << 16, one_chip)
+    assert _lane_names(compiled, scoped=True) == {
+        "ntt_tile_fwd", "ntt_stage_fwd", "modmul", "ntt_tile_inv", "ntt_stage_inv",
+    }
+    assert "/lane.polymul_ntt/" in compiled.as_text()
