@@ -11,7 +11,8 @@ benchmarked through one harness (`benchmarks/tpu_ntt.py`):
              rows.
   pallas     the jax/pallas TPU kernel lane (`kernels.ntt.ntt_pallas`):
              compiled on a TPU, interpreted on the CPU backend (each
-             tile a (rows, 128) slab; see `kernels/ntt.py`); gated on
+             tile a (rows, 128) slab, or batch-major below n = 1024;
+             see `kernels/ntt.py`); gated on
              jax being importable so the package (and this module)
              stay usable without it.
 

@@ -5,7 +5,8 @@ The PIM -> TPU mapping:
   regime A (intra-atom + intra-row)  -> `_ntt_tile_kernel`: ALL stages with
       stride < T fused over a single VMEM-resident tile; one HBM read +
       one HBM write covers log(T) stages (the paper's "process a row-sized
-      block with one row activation").
+      block with one row activation").  A whole transform of n < 1024
+      words runs as `_ntt_rows_kernel` instead, batch-major (below).
   regime B (inter-row)               -> `_ntt_pair_kernel`: one pass per
       remaining stage; each grid step's block CONTAINS both butterfly
       halves (u and v tiles), is updated IN PLACE
@@ -31,6 +32,19 @@ The inter-tile stages keep a tile's (rows, 128) slab as the block's last
 two dims and read their one twiddle per butterfly group from SMEM
 (scalar prefetch), indexed by the grid.
 
+Batch-major layout (`_ntt_rows_kernel`).  A whole transform of n = 128,
+256 or 512 words is a slab of fewer than 8 rows, which would fill only
+n/128 of a vreg's 8 sublanes.  Such a transform (tile == n) keeps the
+(batch, n) input as it is in HBM and blocks it as (bb, n), with bb chosen
+from bytes.  Inside the kernel each 128-lane column chunk of a sub-block
+of rows is one (rows, 128) value, so the batch fills the sublanes.  A
+stage of stride s >= 128 pairs chunk j with chunk j ^ (s/128): plain
+vector arithmetic on whole chunks, with the stage's one twiddle per chunk
+pair a constant and the multiply on the v chunk alone.  A stage of stride
+s < 128 is the lane butterfly above on each chunk, its twiddles one
+(1, 128) row per chunk broadcast over the sublanes.  The arithmetic, the
+stage order and the output order are those of the slab layout.
+
 Twiddles are precomputed tables shared across the batch (the paper's
 on-the-fly (w0, r_w) generation saves DRAM bandwidth; on TPU a serial
 recurrence would idle the VPU, and the tables cost O(T) VMEM).
@@ -42,6 +56,7 @@ interpreted on the CPU backend and compiled on the TPU (`resolve_interpret`).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -54,8 +69,17 @@ from repro.core.ntt import NttContext, forward_stages, inverse_stages
 from repro.kernels import stats
 
 LANES = 128  # words per vector row: the minor dim of every block
+SUBLANES = 8  # rows of a uint32 vreg
 DEFAULT_TILE = 8192  # words: a (64, 128) slab, 32 KiB per row of the batch
 DEFAULT_BATCH_BLOCK = 8
+# batch-major layout: blocks of about ROWS_BLOCK_BYTES, at least
+# ROWS_MIN_STEPS grid steps so the DMAs overlap compute, and sub-blocks of
+# ROWS_SUB_WORDS words (32 vregs) carried through every stage at once: a
+# stage is a chain of dependent ops, and fewer vregs leave the VPU waiting
+# on its latency (4 vregs took 3.7x the time on a v5e)
+ROWS_BLOCK_BYTES = 512 * 1024
+ROWS_MIN_STEPS = 4
+ROWS_SUB_WORDS = 32768
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
@@ -149,6 +173,118 @@ def _tile_tables(ctx: NttContext, tile: int, forward: bool):
 
 
 # ---------------------------------------------------------------------------
+# small-N kernel: every stage over a batch-major (bb, n) block
+# ---------------------------------------------------------------------------
+
+
+def _ntt_rows_kernel(x_ref, tw_ref, o_ref, *, plan, sb, gs, q, scale):
+    """Every stage over a (bb, n) block, sb rows at a time.
+
+    `plan` holds one entry per stage: ("lanes", s, k) for stride s < 128,
+    its twiddles tw_ref[:, k, j] (a (1, 128) row per chunk j); or
+    ("chunks", d, tws) for stride 128 d, tws the (w, shoup(w)) of each
+    lower chunk in order.
+    """
+    chunks = x_ref.shape[1] // LANES
+
+    def sub_block(i, carry):
+        rows = pl.ds(pl.multiple_of(i * sb, sb), sb)
+        c = [x_ref[rows, pl.ds(j * LANES, LANES)] for j in range(chunks)]  # (sb, 128) each
+        for kind, step, tw in plan:
+            if kind == "lanes":
+                for j in range(chunks):
+                    c[j] = _butterfly(c[j], tw_ref[0, tw, j], tw_ref[1, tw, j], step, 1, gs, q)
+                continue
+            lower = [j for j in range(chunks) if not j & step]
+            for lo, (w, w_sh) in zip(lower, tw):
+                u, v = c[lo], c[lo + step]
+                if gs:
+                    c[lo] = mm.addmod_u32(u, v, q)
+                    c[lo + step] = mm.shoup_mulmod_u32(mm.submod_u32(u, v, q), w, w_sh, q)
+                else:
+                    wv = mm.shoup_mulmod_u32(v, w, w_sh, q)
+                    c[lo], c[lo + step] = mm.addmod_u32(u, wv, q), mm.submod_u32(u, wv, q)
+        for j in range(chunks):
+            if scale is not None:
+                c[j] = mm.shoup_mulmod_u32(c[j], np.uint32(scale[0]), np.uint32(scale[1]), q)
+            o_ref[rows, pl.ds(j * LANES, LANES)] = c[j]
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[0] // sb, sub_block, 0)
+
+
+def _rows_plan(ctx: NttContext, forward: bool):
+    """The batch-major stage plan and its lane-stage twiddle rows.
+
+    Returns (plan, tables): tables of shape (2, n_lane_stages, n/128, 1, 128)
+    hold [w, shoup(w)] rows of the stages with stride < 128, as the slab
+    layout's `_tile_tables` lays them out; a stage of stride >= 128 carries
+    its twiddle per chunk pair in the plan.
+    """
+    n = ctx.n
+    table = ctx.psi_brv if forward else ctx.psi_inv_brv
+    table_sh = ctx.psi_brv_shoup if forward else ctx.psi_inv_brv_shoup
+    _, tabs = _tile_tables(ctx, n, forward)
+    plan, lane_ks = [], []
+    for k, st in enumerate(forward_stages(n) if forward else inverse_stages(n)):
+        if st.stride < LANES:
+            plan.append(("lanes", st.stride, len(lane_ks)))
+            lane_ks.append(k)
+            continue
+        d = st.stride // LANES
+        # lower chunk j holds words 128 j .. 128 j + 127: one butterfly group
+        tw = [st.tw_lo + j * LANES // (2 * st.stride) for j in range(n // LANES) if not j & d]
+        plan.append(("chunks", d, tuple((int(table[i]), int(table_sh[i])) for i in tw)))
+    return tuple(plan), np.ascontiguousarray(tabs[0][:, lane_ks, :, None, :])
+
+
+def _round_up(a: int, b: int) -> int:
+    return -(-a // b) * b
+
+
+def _rows_blocks(batch: int, n: int, batch_block: int | None):
+    """(bb, sb): rows of a grid step's block and of an inner sub-block."""
+    sub = max(SUBLANES, ROWS_SUB_WORDS // n)
+    if batch_block:
+        bb = _round_up(batch_block, SUBLANES)
+    else:
+        steps = max(ROWS_MIN_STEPS, -(-batch * n * 4 // ROWS_BLOCK_BYTES))
+        bb = _round_up(-(-batch // steps), SUBLANES)
+        if bb > sub:
+            bb = _round_up(bb, sub)  # whole sub-blocks: a smaller one idles the VPU
+    bb = min(bb, _round_up(batch, SUBLANES))
+    return bb, math.gcd(bb, sub)
+
+
+def _batch_major(x, ctx: NttContext, forward: bool, batch_block, interpret: bool):
+    """The whole transform of (batch, n) rows, n < 8 * 128, in one pass."""
+    n, q = ctx.n, ctx.q
+    batch = x.shape[0]
+    bb, sb = _rows_blocks(batch, n, batch_block)
+    pad = (-batch) % bb
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    plan, tabs = _rows_plan(ctx, forward)
+    scale = None if forward else (ctx.n_inv, ctx.n_inv_shoup)
+    kernel = functools.partial(_ntt_rows_kernel, plan=plan, sb=sb, gs=not forward, q=q, scale=scale)
+    # no input_output_aliases: without a relayout before the call there is
+    # no fresh buffer to give up, and XLA would copy the caller's input
+    out = pl.pallas_call(
+        kernel,
+        grid=(x.shape[0] // bb,),
+        in_specs=[
+            pl.BlockSpec((bb, n), lambda i: (i, 0)),
+            pl.BlockSpec(tabs.shape, lambda i: (0,) * tabs.ndim),
+        ],
+        out_specs=pl.BlockSpec((bb, n), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct(x.shape, jnp.uint32),
+        interpret=interpret,
+        name=f"ntt_tile_{'fwd' if forward else 'inv'}",
+    )(x, jnp.asarray(tabs))
+    return out[:batch] if pad else out
+
+
+# ---------------------------------------------------------------------------
 # regime B kernel: one inter-tile stage, block contains both halves
 # ---------------------------------------------------------------------------
 
@@ -193,9 +329,12 @@ def ntt_pallas(
     inverse: bit-reversed in -> natural out, scaled by 1/N (GS).
     Its device ops carry the scope `lane.ntt` or `lane.intt`; the kernels
     are named `ntt_tile_fwd`/`_inv` (the fused intra-tile pass) and
-    `ntt_stage_fwd`/`_inv` (one inter-tile stage).
+    `ntt_stage_fwd`/`_inv` (one inter-tile stage).  A whole transform of
+    n < 1024 words takes the batch-major layout, on its (batch, n) input as
+    it is, and `batch_block` is rounded up to a multiple of 8 rows there.
     """
-    with stats.scope("ntt" if forward else "intt"):
+    entry = "ntt" if forward else "intt"
+    with stats.scope(entry):
         interpret = resolve_interpret(interpret)
         n = ctx.n
         if x.shape[-1] != n:
@@ -209,6 +348,10 @@ def ntt_pallas(
         squeeze = x.ndim == 1
         if squeeze:
             x = x[None, :]
+        if tile == n and n // LANES < SUBLANES:
+            stats.batch_major_trace(entry)
+            out = _batch_major(x, ctx, forward, batch_block, interpret)
+            return out[0] if squeeze else out
         batch = x.shape[0]
         bb = min(batch_block or DEFAULT_BATCH_BLOCK, batch)
         pad = (-batch) % bb

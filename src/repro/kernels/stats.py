@@ -16,6 +16,8 @@ The jitted bodies run inside `scope(name)`: a `jax.named_scope` of the same
 carries it in its `op_name`, and one more count of `traces`.  A jitted body
 runs only while JAX traces it, so `traces` counts the times the entry was
 traced anew (a new shape or static argument); in a warm loop it stays put.
+`batch_major_traces` counts those of the traces that built the small-n
+transform's batch-major kernel (`kernels.ntt._batch_major`).
 
 A call made while an outer `jax.jit` traces counts once per trace of the
 outer function, with the tracing's host time.  `counters()` returns a
@@ -35,8 +37,8 @@ from jax.profiler import TraceAnnotation
 PREFIX = "lane."
 #: A call longer than this is counted in `over_50ms`: a stall of the host.
 SLOW_NS = 50_000_000
-FIELDS = ("calls", "rows", "host_ns", "host_ns_max", "over_50ms", "traces")
-_CALLS, _ROWS, _NS, _MAX, _SLOW, _TRACES = range(len(FIELDS))
+FIELDS = ("calls", "rows", "host_ns", "host_ns_max", "over_50ms", "traces", "batch_major_traces")
+_CALLS, _ROWS, _NS, _MAX, _SLOW, _TRACES, _BATCH_MAJOR = range(len(FIELDS))
 
 _lock = threading.Lock()
 _counts: dict[str, list[int]] = {}
@@ -66,6 +68,12 @@ def scope(name: str):
     with _lock:
         _counts.setdefault(name, [0] * len(FIELDS))[_TRACES] += 1
     return jax.named_scope(PREFIX + name)
+
+
+def batch_major_trace(name: str) -> None:
+    """Counts one program of entry `name` built with the batch-major layout."""
+    with _lock:
+        _counts.setdefault(name, [0] * len(FIELDS))[_BATCH_MAJOR] += 1
 
 
 def counters() -> dict[str, dict[str, int]]:

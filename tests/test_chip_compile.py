@@ -117,3 +117,20 @@ def test_polymul_kernels_are_named_and_scoped_for_v5e(one_chip):
         "ntt_tile_fwd", "ntt_stage_fwd", "modmul", "ntt_tile_inv", "ntt_stage_inv",
     }
     assert "/lane.polymul_ntt/" in compiled.as_text()
+
+
+@pytest.mark.parametrize("forward,batch", [(True, 6144), (False, 3072)], ids=["forward", "inverse"])
+def test_small_n_is_one_kernel_on_its_input_for_v5e(one_chip, forward, batch):
+    """At the ML-DSA shapes the whole program is the kernel on the (batch, 256)
+    input as it is: no relayout before or after it, no copy for an alias."""
+    ctx = make_context(mm.DEFAULT_Q, 256)
+    arg = jax.ShapeDtypeStruct((batch, 256), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(lambda x: ntt_pallas(x, ctx, forward=forward, interpret=False)).lower(arg).compile()
+    text = compiled.as_text()
+    opcodes = re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = \S+ ([\w\-]+)\(", text, re.M)
+    assert sorted(opcodes) == ["constant", "custom-call", "parameter"], opcodes
+    way = "fwd" if forward else "inv"
+    assert _lane_names(compiled, scoped=True) == {f"ntt_tile_{way}"}
+    assert re.search(rf"%ntt_tile_{way}\.\d+ = u32\[{batch},256\]", text)
+    assert "input_output_alias" not in text
+    assert compiled.memory_analysis().alias_size_in_bytes == 0

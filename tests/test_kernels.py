@@ -35,6 +35,14 @@ SHAPES = [
     (4, 8192, 1024, 2),
     (1, 16384, 2048, None),
     (2, 16384, 4096, 2),
+    # batch-major path (a whole transform of n < 1024 words): batches that
+    # are not a multiple of the block, an explicit block below 8 rows
+    (13, 128, None, None),
+    (700, 128, None, 3),
+    (13, 256, None, None),
+    (700, 256, None, None),
+    (1, 512, None, None),
+    (700, 512, None, 5),
 ]
 
 
@@ -49,7 +57,7 @@ def test_ntt_kernel_matches_ref(batch, n, tile, bb, forward):
     np.testing.assert_array_equal(got, exp)
 
 
-@pytest.mark.parametrize("n,tile", [(1024, None), (8192, 1024)])
+@pytest.mark.parametrize("n,tile", [(128, None), (256, None), (512, None), (1024, None), (8192, 1024)])
 def test_ntt_kernel_roundtrip(n, tile):
     ctx = make_context(Q, n)
     x = rand((3, n))

@@ -105,6 +105,20 @@ def test_a_trace_is_counted_once_per_new_shape():
     assert "intt" not in c  # nothing traced or called under it
 
 
+def test_batch_major_traces_count_small_n_programs():
+    ntt_pallas.clear_cache()
+    ops.ntt(rand((2, 4096)), make_context(Q, 4096))  # the slab layout
+    assert ops.counters()["ntt"]["traces"] == 1
+    assert ops.counters()["ntt"]["batch_major_traces"] == 0
+    ctx = make_context(Q, 256)
+    for shape in [(4, 256), (4, 256), (9, 256)]:
+        ops.ntt(rand(shape), ctx)
+    ops.intt(rand((4, 128)), make_context(Q, 128))
+    c = ops.counters()
+    assert (c["ntt"]["traces"], c["ntt"]["batch_major_traces"]) == (3, 2)
+    assert (c["intt"]["traces"], c["intt"]["batch_major_traces"]) == (1, 1)
+
+
 def test_slow_calls_are_counted(monkeypatch):
     monkeypatch.setattr(stats, "SLOW_NS", 0)
     ctx = make_context(Q, 256)
