@@ -40,14 +40,22 @@ from bytes.  Inside the kernel each 128-lane column chunk of a sub-block
 of rows is one (rows, 128) value, so the batch fills the sublanes.  A
 stage of stride s >= 128 pairs chunk j with chunk j ^ (s/128): plain
 vector arithmetic on whole chunks, with the stage's one twiddle per chunk
-pair a constant and the multiply on the v chunk alone.  A stage of stride
+pair a scalar and the multiply on the v chunk alone.  A stage of stride
 s < 128 is the lane butterfly above on each chunk, its twiddles one
 (1, 128) row per chunk broadcast over the sublanes.  The arithmetic, the
 stage order and the output order are those of the slab layout.
 
-Twiddles are precomputed tables shared across the batch (the paper's
-on-the-fly (w0, r_w) generation saves DRAM bandwidth; on TPU a serial
-recurrence would idle the VPU, and the tables cost O(T) VMEM).
+The modulus is data.  Each kernel reads q, and the inverse's 1/N with its
+Shoup companion, from a small scalar operand in SMEM (scalar prefetch),
+which also holds the one (w, shoup(w)) of each inter-tile butterfly group
+or chunk pair; the per-element twiddle rows are a device operand too.  So
+one compiled program per (n, direction, tile, batch block, shape) serves
+every modulus: an RNS basis of many towers compiles its transforms once.
+`ntt_pallas` builds a context's tables (`Tables`) on first use, places
+them on the device and keeps them (`device_tables`).  Twiddles are
+precomputed tables, not generated on the fly (the paper's (w0, r_w)
+recurrence saves DRAM bandwidth; on TPU a serial recurrence would idle the
+VPU, and the tables cost O(T) VMEM).
 
 All arithmetic is uint32 with 16-bit-limb emulation of 32x32->64
 products (TPUs have no 64-bit integer multiply); q < 2^31.  Kernels run
@@ -57,6 +65,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -106,7 +115,7 @@ def resolve_interpret(interpret: bool | None) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def _butterfly(x, w, w_sh, stride: int, axis: int, gs: bool, q: int):
+def _butterfly(x, w, w_sh, stride: int, axis: int, gs: bool, q):
     """One stage: element i pairs with i ^ stride along `axis`.
 
     `w` holds the pair's twiddle at the upper element and 1 at the lower.
@@ -130,11 +139,88 @@ def _butterfly(x, w, w_sh, stride: int, axis: int, gs: bool, q: int):
 
 
 # ---------------------------------------------------------------------------
+# a context's operands: the modulus and its tables, on the device
+# ---------------------------------------------------------------------------
+
+#: scalars[:HEAD] of every transform: q, n^-1 mod q and its Shoup companion
+HEAD = 3
+
+
+class Tables(NamedTuple):
+    """The operands of one context's transforms in one direction and tile.
+
+    scalars  u32, read by the kernels from SMEM: q, n_inv, shoup(n_inv),
+             then on the slab path, for each inter-tile stage in pass order,
+             its groups' w and then their shoup(w); batch-major, (w,
+             shoup(w)) of each chunk pair in plan order
+    tile     the per-element twiddles of the stages inside a tile:
+             (n_tiles, 2, stages, rows, 128) on the slab path,
+             (2, lane_stages, n/128, 1, 128) batch-major
+    """
+
+    scalars: object
+    tile: object
+
+
+def _batch_major_shape(n: int, tile: int) -> bool:
+    """A whole transform of fewer than 8 slab rows runs batch-major."""
+    return tile == n and n // LANES < SUBLANES
+
+
+def _plan(n: int, forward: bool):
+    return forward_stages(n) if forward else inverse_stages(n)
+
+
+def _twiddles(ctx: NttContext, forward: bool):
+    if forward:
+        return ctx.psi_brv, ctx.psi_brv_shoup
+    return ctx.psi_inv_brv, ctx.psi_inv_brv_shoup
+
+
+def _host_tables(ctx: NttContext, forward: bool, tile: int) -> Tables:
+    """`Tables` of `ctx` as numpy arrays."""
+    n = ctx.n
+    table, table_sh = _twiddles(ctx, forward)
+    head = [ctx.q, ctx.n_inv, ctx.n_inv_shoup]
+    _, tabs = _tile_tables(ctx, tile, forward)
+    if _batch_major_shape(n, tile):
+        _, lane_ks, chunk_tw = _rows_plan(n, forward)
+        pairs = [int(v) for i in chunk_tw for v in (table[i], table_sh[i])]
+        lanes = np.ascontiguousarray(tabs[0][:, list(lane_ks), :, None, :])
+        return Tables(np.array(head + pairs, np.uint32), lanes)
+    # the twiddle of an inter-tile stage depends only on its group g: the
+    # u tile of (g, s) starts at (2 g st_tiles + s) tile, over 2 stride = g
+    stages = [
+        np.concatenate([table[h : 2 * h], table_sh[h : 2 * h]])
+        for h in (n // (2 * st.stride) for st in _plan(n, forward) if st.stride >= tile)
+    ]
+    return Tables(np.concatenate([np.array(head, np.uint32), *stages]).astype(np.uint32), tabs)
+
+
+@functools.cache
+def device_tables(ctx: NttContext, forward: bool, tile: int) -> Tables:
+    """`Tables` of `ctx` on the default device, built on first use and kept.
+
+    A build is counted and spanned (`stats.tables`) under the entry of its
+    direction.  It runs eagerly even while an outer `jax.jit` traces the
+    caller, so the cache never holds a tracer (that caller's program then
+    holds the tables as constants).
+    """
+
+    def build():
+        with jax.ensure_compile_time_eval():
+            return jax.device_put(_host_tables(ctx, forward, tile))
+
+    return stats.tables("ntt" if forward else "intt", ctx.q, ctx.n, build)
+
+
+# ---------------------------------------------------------------------------
 # regime A kernel: fused stages over one VMEM tile
 # ---------------------------------------------------------------------------
 
 
-def _ntt_tile_kernel(x_ref, tw_ref, o_ref, *, strides, gs, q, scale):
+def _ntt_tile_kernel(s_ref, x_ref, tw_ref, o_ref, *, strides, gs, scale, interpret):
+    q = s_ref[0]
     x = x_ref[...]  # (bb, rows, 128)
     for k, s in enumerate(strides):
         w, w_sh = tw_ref[0, k], tw_ref[1, k]  # (rows, 128) each
@@ -142,10 +228,25 @@ def _ntt_tile_kernel(x_ref, tw_ref, o_ref, *, strides, gs, q, scale):
             x = _butterfly(x, w, w_sh, s, 2, gs, q)
         else:
             x = _butterfly(x, w, w_sh, s // LANES, 1, gs, q)
-    if scale is not None:
-        n_inv, n_inv_sh = scale
-        x = mm.shoup_mulmod_u32(x, np.uint32(n_inv), np.uint32(n_inv_sh), q)
+        if interpret:
+            x = _stage_boundary(x, q)
+    if scale:
+        x = mm.shoup_mulmod_u32(x, s_ref[1], s_ref[2], q)
     o_ref[...] = x
+
+
+def _stage_boundary(x, q):
+    """`x`, through a conditional that XLA cannot fuse across.
+
+    Interpreted on the CPU, the kernel body is plain XLA, which fuses a
+    stage's rolled rows into the next stage and so recomputes every earlier
+    row stage inside each later one: a tile's time grows with the square of
+    its stages (0.43 s instead of 0.13 s for a forward transform of 8 x
+    65536 words on one core).
+    The branch is taken whenever q > 0, that is always.  Compiled for the
+    TPU the body is one Mosaic kernel, which needs no such boundary.
+    """
+    return jax.lax.cond(q > 0, lambda v: v, jnp.zeros_like, x)
 
 
 def _tile_tables(ctx: NttContext, tile: int, forward: bool):
@@ -156,10 +257,8 @@ def _tile_tables(ctx: NttContext, tile: int, forward: bool):
     w at the upper element of each pair and 1 at the lower.
     """
     n, q = ctx.n, ctx.q
-    table = ctx.psi_brv if forward else ctx.psi_inv_brv
-    table_sh = ctx.psi_brv_shoup if forward else ctx.psi_inv_brv_shoup
-    plan = forward_stages(n) if forward else inverse_stages(n)
-    stages = [st for st in plan if st.stride < tile]
+    table, table_sh = _twiddles(ctx, forward)
+    stages = [st for st in _plan(n, forward) if st.stride < tile]
     pos = np.arange(n)
     w = np.ones((len(stages), n), np.uint32)
     w_sh = np.full((len(stages), n), mm.shoup(1, q), np.uint32)
@@ -177,15 +276,16 @@ def _tile_tables(ctx: NttContext, tile: int, forward: bool):
 # ---------------------------------------------------------------------------
 
 
-def _ntt_rows_kernel(x_ref, tw_ref, o_ref, *, plan, sb, gs, q, scale):
+def _ntt_rows_kernel(s_ref, x_ref, tw_ref, o_ref, *, plan, sb, gs, scale):
     """Every stage over a (bb, n) block, sb rows at a time.
 
     `plan` holds one entry per stage: ("lanes", s, k) for stride s < 128,
     its twiddles tw_ref[:, k, j] (a (1, 128) row per chunk j); or
-    ("chunks", d, tws) for stride 128 d, tws the (w, shoup(w)) of each
-    lower chunk in order.
+    ("chunks", d, at) for stride 128 d, the (w, shoup(w)) of each lower
+    chunk in order at s_ref[at], s_ref[at + 1], ...
     """
     chunks = x_ref.shape[1] // LANES
+    q = s_ref[0]
 
     def sub_block(i, carry):
         rows = pl.ds(pl.multiple_of(i * sb, sb), sb)
@@ -196,7 +296,8 @@ def _ntt_rows_kernel(x_ref, tw_ref, o_ref, *, plan, sb, gs, q, scale):
                     c[j] = _butterfly(c[j], tw_ref[0, tw, j], tw_ref[1, tw, j], step, 1, gs, q)
                 continue
             lower = [j for j in range(chunks) if not j & step]
-            for lo, (w, w_sh) in zip(lower, tw):
+            for m, lo in enumerate(lower):
+                w, w_sh = s_ref[tw + 2 * m], s_ref[tw + 2 * m + 1]
                 u, v = c[lo], c[lo + step]
                 if gs:
                     c[lo] = mm.addmod_u32(u, v, q)
@@ -205,37 +306,35 @@ def _ntt_rows_kernel(x_ref, tw_ref, o_ref, *, plan, sb, gs, q, scale):
                     wv = mm.shoup_mulmod_u32(v, w, w_sh, q)
                     c[lo], c[lo + step] = mm.addmod_u32(u, wv, q), mm.submod_u32(u, wv, q)
         for j in range(chunks):
-            if scale is not None:
-                c[j] = mm.shoup_mulmod_u32(c[j], np.uint32(scale[0]), np.uint32(scale[1]), q)
+            if scale:
+                c[j] = mm.shoup_mulmod_u32(c[j], s_ref[1], s_ref[2], q)
             o_ref[rows, pl.ds(j * LANES, LANES)] = c[j]
         return carry
 
     jax.lax.fori_loop(0, x_ref.shape[0] // sb, sub_block, 0)
 
 
-def _rows_plan(ctx: NttContext, forward: bool):
-    """The batch-major stage plan and its lane-stage twiddle rows.
+@functools.lru_cache(maxsize=None)
+def _rows_plan(n: int, forward: bool):
+    """The batch-major stage plan of `_ntt_rows_kernel`.
 
-    Returns (plan, tables): tables of shape (2, n_lane_stages, n/128, 1, 128)
-    hold [w, shoup(w)] rows of the stages with stride < 128, as the slab
-    layout's `_tile_tables` lays them out; a stage of stride >= 128 carries
-    its twiddle per chunk pair in the plan.
+    Returns (plan, lane_ks, chunk_tw): lane_ks the indices, among all
+    stages, of the stages with stride < 128, whose twiddle rows `Tables.tile`
+    holds as the slab layout's `_tile_tables` lays them out; chunk_tw the
+    twiddle-table index of each chunk pair of the stages with stride >= 128,
+    in the order `Tables.scalars` holds them after its HEAD.
     """
-    n = ctx.n
-    table = ctx.psi_brv if forward else ctx.psi_inv_brv
-    table_sh = ctx.psi_brv_shoup if forward else ctx.psi_inv_brv_shoup
-    _, tabs = _tile_tables(ctx, n, forward)
-    plan, lane_ks = [], []
-    for k, st in enumerate(forward_stages(n) if forward else inverse_stages(n)):
+    plan, lane_ks, chunk_tw = [], [], []
+    for k, st in enumerate(_plan(n, forward)):
         if st.stride < LANES:
             plan.append(("lanes", st.stride, len(lane_ks)))
             lane_ks.append(k)
             continue
         d = st.stride // LANES
+        plan.append(("chunks", d, HEAD + 2 * len(chunk_tw)))
         # lower chunk j holds words 128 j .. 128 j + 127: one butterfly group
-        tw = [st.tw_lo + j * LANES // (2 * st.stride) for j in range(n // LANES) if not j & d]
-        plan.append(("chunks", d, tuple((int(table[i]), int(table_sh[i])) for i in tw)))
-    return tuple(plan), np.ascontiguousarray(tabs[0][:, lane_ks, :, None, :])
+        chunk_tw += [st.tw_lo + j * LANES // (2 * st.stride) for j in range(n // LANES) if not j & d]
+    return tuple(plan), tuple(lane_ks), tuple(chunk_tw)
 
 
 def _round_up(a: int, b: int) -> int:
@@ -256,31 +355,33 @@ def _rows_blocks(batch: int, n: int, batch_block: int | None):
     return bb, math.gcd(bb, sub)
 
 
-def _batch_major(x, ctx: NttContext, forward: bool, batch_block, interpret: bool):
+def _batch_major(x, tabs: Tables, forward: bool, batch_block, interpret: bool):
     """The whole transform of (batch, n) rows, n < 8 * 128, in one pass."""
-    n, q = ctx.n, ctx.q
-    batch = x.shape[0]
+    batch, n = x.shape
     bb, sb = _rows_blocks(batch, n, batch_block)
     pad = (-batch) % bb
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
-    plan, tabs = _rows_plan(ctx, forward)
-    scale = None if forward else (ctx.n_inv, ctx.n_inv_shoup)
-    kernel = functools.partial(_ntt_rows_kernel, plan=plan, sb=sb, gs=not forward, q=q, scale=scale)
+    plan, _, _ = _rows_plan(n, forward)
+    kernel = functools.partial(_ntt_rows_kernel, plan=plan, sb=sb, gs=not forward, scale=not forward)
+    lanes = tabs.tile
     # no input_output_aliases: without a relayout before the call there is
     # no fresh buffer to give up, and XLA would copy the caller's input
     out = pl.pallas_call(
         kernel,
-        grid=(x.shape[0] // bb,),
-        in_specs=[
-            pl.BlockSpec((bb, n), lambda i: (i, 0)),
-            pl.BlockSpec(tabs.shape, lambda i: (0,) * tabs.ndim),
-        ],
-        out_specs=pl.BlockSpec((bb, n), lambda i: (i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(x.shape[0] // bb,),
+            in_specs=[
+                pl.BlockSpec((bb, n), lambda i, s: (i, 0)),
+                pl.BlockSpec(lanes.shape, lambda i, s: (0,) * lanes.ndim),
+            ],
+            out_specs=pl.BlockSpec((bb, n), lambda i, s: (i, 0)),
+        ),
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.uint32),
         interpret=interpret,
         name=f"ntt_tile_{'fwd' if forward else 'inv'}",
-    )(x, jnp.asarray(tabs))
+    )(tabs.scalars, x, lanes)
     return out[:batch] if pad else out
 
 
@@ -289,11 +390,12 @@ def _batch_major(x, ctx: NttContext, forward: bool, batch_block, interpret: bool
 # ---------------------------------------------------------------------------
 
 
-def _ntt_pair_kernel(tw_ref, x_ref, o_ref, *, gs, q):
-    # block (bb, 2, rows, 128): dim 1 separates the butterfly halves;
-    # tw_ref is the (2, n_groups) [w, shoup(w)] table in SMEM.
+def _ntt_pair_kernel(s_ref, x_ref, o_ref, *, gs, at, groups):
+    # block (bb, 2, rows, 128): dim 1 separates the butterfly halves; the
+    # stage's w of group g is s_ref[at + g], its shoup(w) s_ref[at + groups + g]
+    q = s_ref[0]
     g = pl.program_id(1)
-    w, w_sh = tw_ref[0, g], tw_ref[1, g]
+    w, w_sh = s_ref[at + g], s_ref[at + groups + g]
     u = x_ref[:, 0]
     v = x_ref[:, 1]
     if gs:
@@ -312,9 +414,6 @@ def _ntt_pair_kernel(tw_ref, x_ref, o_ref, *, gs, q):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(
-    jax.jit, static_argnames=("ctx", "forward", "tile", "batch_block", "interpret")
-)
 def ntt_pallas(
     x,
     ctx: NttContext,
@@ -327,111 +426,117 @@ def ntt_pallas(
 
     forward: natural order in -> bit-reversed out (CT butterflies).
     inverse: bit-reversed in -> natural out, scaled by 1/N (GS).
+    The context's modulus and tables are operands of the compiled program
+    (`device_tables`), so every context of one n shares its programs.
     Its device ops carry the scope `lane.ntt` or `lane.intt`; the kernels
     are named `ntt_tile_fwd`/`_inv` (the fused intra-tile pass) and
     `ntt_stage_fwd`/`_inv` (one inter-tile stage).  A whole transform of
     n < 1024 words takes the batch-major layout, on its (batch, n) input as
     it is, and `batch_block` is rounded up to a multiple of 8 rows there.
     """
+    n = ctx.n
+    if x.shape[-1] != n:
+        raise ValueError(f"last axis is {x.shape[-1]}, the context is for n={n}")
+    tile = min(tile or DEFAULT_TILE, n)
+    if tile % LANES or tile & (tile - 1):
+        raise ValueError(
+            f"tile must be a power of two and a multiple of {LANES} words, got "
+            f"{tile}: each tile is laid out as rows of {LANES} lanes"
+        )
+    tabs = device_tables(ctx, forward, tile)
+    return _transform(x, tabs, forward=forward, tile=tile, batch_block=batch_block, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("forward", "tile", "batch_block", "interpret"))
+def _transform(x, tabs: Tables, forward: bool, tile: int, batch_block, interpret):
+    """The compiled transform: keyed by n (the shape), direction, tile,
+    batch block and interpret, never by the modulus."""
     entry = "ntt" if forward else "intt"
     with stats.scope(entry):
         interpret = resolve_interpret(interpret)
-        n = ctx.n
-        if x.shape[-1] != n:
-            raise ValueError(f"last axis is {x.shape[-1]}, the context is for n={n}")
-        tile = min(tile or DEFAULT_TILE, n)
-        if tile % LANES or tile & (tile - 1):
-            raise ValueError(
-                f"tile must be a power of two and a multiple of {LANES} words, got "
-                f"{tile}: each tile is laid out as rows of {LANES} lanes"
-            )
+        n = x.shape[-1]
         squeeze = x.ndim == 1
         if squeeze:
             x = x[None, :]
-        if tile == n and n // LANES < SUBLANES:
+        if _batch_major_shape(n, tile):
             stats.batch_major_trace(entry)
-            out = _batch_major(x, ctx, forward, batch_block, interpret)
+            out = _batch_major(x, tabs, forward, batch_block, interpret)
             return out[0] if squeeze else out
         batch = x.shape[0]
         bb = min(batch_block or DEFAULT_BATCH_BLOCK, batch)
         pad = (-batch) % bb
         if pad:
             x = jnp.pad(x, ((0, pad), (0, 0)))
-        out = _two_regime(x, ctx, forward, tile, bb, interpret)
+        out = _two_regime(x, tabs, forward, tile, bb, interpret)
         if pad:
             out = out[:batch]
         return out[0] if squeeze else out
 
 
-def _two_regime(x, ctx, forward, tile, bb, interpret):
+def _two_regime(x, tabs: Tables, forward, tile, bb, interpret):
     """Fused intra-tile pass + one in-place pass per inter-tile stage.
 
     With tile == n there are no inter-tile stages and the whole transform,
     1/N scale included, is one fused pass.
     """
-    n, q = ctx.n, ctx.q
-    batch = x.shape[0]
+    batch, n = x.shape
     n_tiles = n // tile
     rows = tile // LANES
-    table = ctx.psi_brv if forward else ctx.psi_inv_brv
-    table_sh = ctx.psi_brv_shoup if forward else ctx.psi_inv_brv_shoup
-    plan = forward_stages(n) if forward else inverse_stages(n)
+    plan = _plan(n, forward)
+    strides = tuple(st.stride for st in plan if st.stride < tile)
     inter = [st for st in plan if st.stride >= tile]
-    scale = None if forward else (ctx.n_inv, ctx.n_inv_shoup)
     way = "fwd" if forward else "inv"
 
     def run_intra(x, scale):
-        strides, tabs = _tile_tables(ctx, tile, forward)
-        kernel = functools.partial(_ntt_tile_kernel, strides=strides, gs=not forward, q=q, scale=scale)
+        kernel = functools.partial(_ntt_tile_kernel, strides=strides, gs=not forward, scale=scale, interpret=interpret)
         xr = x.reshape(batch, n_tiles, rows, LANES)
         # tiles outermost: a tile's twiddle block is fetched once, not per batch block
         out = pl.pallas_call(
             kernel,
-            grid=(n_tiles, batch // bb),
-            in_specs=[
-                pl.BlockSpec((bb, None, rows, LANES), lambda j, i: (i, j, 0, 0)),
-                pl.BlockSpec((None, 2, len(strides), rows, LANES), lambda j, i: (j, 0, 0, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((bb, None, rows, LANES), lambda j, i: (i, j, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct(xr.shape, jnp.uint32),
-            input_output_aliases={0: 0},
-            interpret=interpret,
-            name=f"ntt_tile_{way}",
-        )(xr, jnp.asarray(tabs))
-        return out.reshape(batch, n)
-
-    def run_inter_stage(x, st):
-        st_tiles = st.stride // tile
-        n_groups = n_tiles // (2 * st_tiles)
-        h = n // (2 * st.stride)
-        # twiddle depends only on the group index g: u-tile offset
-        # = (g*2*st_tiles + s)*tile, and (offset)/(2*stride) = g.
-        tw = np.stack([table[h : h + n_groups], table_sh[h : h + n_groups]]).astype(np.uint32)
-        x6 = x.reshape(batch, n_groups, 2, st_tiles, rows, LANES)
-        block = pl.BlockSpec((bb, None, 2, None, rows, LANES), lambda i, g, s, tw: (i, g, 0, s, 0, 0))
-        out = pl.pallas_call(
-            functools.partial(_ntt_pair_kernel, gs=st.gs, q=q),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
-                grid=(batch // bb, n_groups, st_tiles),
-                in_specs=[block],
-                out_specs=block,
+                grid=(n_tiles, batch // bb),
+                in_specs=[
+                    pl.BlockSpec((bb, None, rows, LANES), lambda j, i, s: (i, j, 0, 0)),
+                    pl.BlockSpec((None, 2, len(strides), rows, LANES), lambda j, i, s: (j, 0, 0, 0, 0)),
+                ],
+                out_specs=pl.BlockSpec((bb, None, rows, LANES), lambda j, i, s: (i, j, 0, 0)),
             ),
-            out_shape=jax.ShapeDtypeStruct(x6.shape, jnp.uint32),
+            out_shape=jax.ShapeDtypeStruct(xr.shape, jnp.uint32),
             input_output_aliases={1: 0},
             interpret=interpret,
-            name=f"ntt_stage_{way}",
-        )(jnp.asarray(tw), x6)
+            name=f"ntt_tile_{way}",
+        )(tabs.scalars, xr, tabs.tile)
         return out.reshape(batch, n)
 
+    def run_inter_stages(x):
+        at = HEAD  # the stages' twiddles follow the head of the scalars, in pass order
+        for st in inter:
+            st_tiles = st.stride // tile
+            n_groups = n_tiles // (2 * st_tiles)
+            x6 = x.reshape(batch, n_groups, 2, st_tiles, rows, LANES)
+            block = pl.BlockSpec((bb, None, 2, None, rows, LANES), lambda i, g, s, sc: (i, g, 0, s, 0, 0))
+            x6 = pl.pallas_call(
+                functools.partial(_ntt_pair_kernel, gs=st.gs, at=at, groups=n_groups),
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=1,
+                    grid=(batch // bb, n_groups, st_tiles),
+                    in_specs=[block],
+                    out_specs=block,
+                ),
+                out_shape=jax.ShapeDtypeStruct(x6.shape, jnp.uint32),
+                input_output_aliases={1: 0},
+                interpret=interpret,
+                name=f"ntt_stage_{way}",
+            )(tabs.scalars, x6)
+            x = x6.reshape(batch, n)
+            at += 2 * n_groups
+        return x
+
     if not inter:
-        return run_intra(x, scale)
-    if forward:
-        for st in inter:  # large strides first
-            x = run_inter_stage(x, st)
-        return run_intra(x, None)
-    x = run_intra(x, None)
-    for st in inter:
-        x = run_inter_stage(x, st)
-    n_inv, n_inv_sh = scale
-    return mm.shoup_mulmod_u32(x, np.uint32(n_inv), np.uint32(n_inv_sh), q)
+        return run_intra(x, not forward)
+    if forward:  # large strides first
+        return run_intra(run_inter_stages(x), False)
+    x = run_inter_stages(run_intra(x, False))
+    q, n_inv, n_inv_sh = tabs.scalars[0], tabs.scalars[1], tabs.scalars[2]
+    return mm.shoup_mulmod_u32(x, n_inv, n_inv_sh, q)

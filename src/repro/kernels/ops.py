@@ -13,9 +13,16 @@ Batching across independent transforms == the paper's bank-level
 parallelism (the batch grid axis of each kernel).  The ops run on one
 device; nothing here shards them across chips.
 
+`ntt` and `intt` take the modulus as data: a context's modulus, 1/N and
+twiddle tables are built once, placed on the device and cached
+(`kernels.ntt.device_tables`), and are operands of one compiled transform
+per ring size, direction and shape, so the towers of an RNS basis share
+their programs.  The product's pointwise step still compiles per context.
+
 `ntt`, `intt` and `polymul_ntt` each run inside a host span `lane.<name>`
-and count their calls; `counters()` and `reset_counters()` read and zero
-the counts (`repro.kernels.stats`).
+and count their calls; a context's table build runs inside `lane.tables`
+and is counted under its direction's entry; `counters()` and
+`reset_counters()` read and zero the counts (`repro.kernels.stats`).
 """
 from __future__ import annotations
 

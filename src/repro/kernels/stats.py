@@ -19,6 +19,13 @@ traced anew (a new shape or static argument); in a warm loop it stays put.
 `batch_major_traces` counts those of the traces that built the small-n
 transform's batch-major kernel (`kernels.ntt._batch_major`).
 
+A context's tables (its modulus and twiddles, the operands of the compiled
+transform: `kernels.ntt.device_tables`) are built and placed on the device
+once, through `tables(name, q, n, build)`: a host span `lane.tables` (args
+`q`, `n`, `bytes`) around the build, and counts `tables` and `table_bytes`
+under the entry of its direction.  Like `traces`, they stay put in a warm
+loop, whatever the number of moduli.
+
 A call made while an outer `jax.jit` traces counts once per trace of the
 outer function, with the tracing's host time.  `counters()` returns a
 snapshot of every entry called or traced so far; `reset_counters()` zeroes
@@ -37,8 +44,10 @@ from jax.profiler import TraceAnnotation
 PREFIX = "lane."
 #: A call longer than this is counted in `over_50ms`: a stall of the host.
 SLOW_NS = 50_000_000
-FIELDS = ("calls", "rows", "host_ns", "host_ns_max", "over_50ms", "traces", "batch_major_traces")
-_CALLS, _ROWS, _NS, _MAX, _SLOW, _TRACES, _BATCH_MAJOR = range(len(FIELDS))
+FIELDS = (
+    "calls", "rows", "host_ns", "host_ns_max", "over_50ms", "traces", "batch_major_traces", "tables", "table_bytes",
+)
+_CALLS, _ROWS, _NS, _MAX, _SLOW, _TRACES, _BATCH_MAJOR, _TABLES, _TABLE_BYTES = range(len(FIELDS))
 
 _lock = threading.Lock()
 _counts: dict[str, list[int]] = {}
@@ -74,6 +83,20 @@ def batch_major_trace(name: str) -> None:
     """Counts one program of entry `name` built with the batch-major layout."""
     with _lock:
         _counts.setdefault(name, [0] * len(FIELDS))[_BATCH_MAJOR] += 1
+
+
+def tables(name: str, q: int, n: int, build):
+    """`build()`, the device tables of one context of entry `name`, under the
+    host span `lane.tables`; counts one build and the bytes it placed."""
+    with TraceAnnotation(PREFIX + "tables", q=q, n=n) as span:
+        out = build()
+        nbytes = sum(a.nbytes for a in jax.tree.leaves(out))
+        span.set_metadata(bytes=nbytes)
+    with _lock:
+        c = _counts.setdefault(name, [0] * len(FIELDS))
+        c[_TABLES] += 1
+        c[_TABLE_BYTES] += nbytes
+    return out
 
 
 def counters() -> dict[str, dict[str, int]]:

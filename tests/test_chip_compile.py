@@ -19,6 +19,7 @@ from repro.core import modmath as mm  # noqa: E402
 from repro.core.ntt import make_context  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.kernels.modmul import modmul_pallas  # noqa: E402
+from repro.kernels import ntt  # noqa: E402
 from repro.kernels.ntt import ntt_pallas  # noqa: E402
 
 BATCH = 64
@@ -119,18 +120,81 @@ def test_polymul_kernels_are_named_and_scoped_for_v5e(one_chip):
     assert "/lane.polymul_ntt/" in compiled.as_text()
 
 
+def _opcodes(text: str) -> list:
+    return re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = \S+ ([\w\-]+)\(", text, re.M)
+
+
+def _primitives(jaxpr) -> set:
+    """The names of every primitive in `jaxpr` and in the jaxprs nested in
+    its equations (jit bodies, kernel bodies)."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names |= _primitives(sub)
+    return names
+
+
+def _entry_program(ctx, forward, batch, sharding):
+    """The program an eager `ntt_pallas(x, ctx)` call runs, lowered: the
+    context's modulus and tables are its operands (`ntt.device_tables`)."""
+    tile = min(ntt.DEFAULT_TILE, ctx.n)
+    tabs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), ntt.device_tables(ctx, forward, tile)
+    )
+    arg = jax.ShapeDtypeStruct((batch, ctx.n), jnp.uint32, sharding=sharding)
+    return ntt._transform.lower(arg, tabs, forward=forward, tile=tile, batch_block=None, interpret=False)
+
+
 @pytest.mark.parametrize("forward,batch", [(True, 6144), (False, 3072)], ids=["forward", "inverse"])
 def test_small_n_is_one_kernel_on_its_input_for_v5e(one_chip, forward, batch):
     """At the ML-DSA shapes the whole program is the kernel on the (batch, 256)
-    input as it is: no relayout before or after it, no copy for an alias."""
+    input as it is: no relayout before or after it, no copy for an alias, and
+    the modulus and twiddles are its operands (scalars and lane rows)."""
     ctx = make_context(mm.DEFAULT_Q, 256)
-    arg = jax.ShapeDtypeStruct((batch, 256), jnp.uint32, sharding=one_chip)
-    compiled = jax.jit(lambda x: ntt_pallas(x, ctx, forward=forward, interpret=False)).lower(arg).compile()
+    compiled = _entry_program(ctx, forward, batch, one_chip).compile()
     text = compiled.as_text()
-    opcodes = re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = \S+ ([\w\-]+)\(", text, re.M)
-    assert sorted(opcodes) == ["constant", "custom-call", "parameter"], opcodes
+    assert sorted(_opcodes(text)) == ["custom-call", "parameter", "parameter", "parameter"], _opcodes(text)
     way = "fwd" if forward else "inv"
     assert _lane_names(compiled, scoped=True) == {f"ntt_tile_{way}"}
     assert re.search(rf"%ntt_tile_{way}\.\d+ = u32\[{batch},256\]", text)
     assert "input_output_alias" not in text
     assert compiled.memory_analysis().alias_size_in_bytes == 0
+
+
+#: Two towers of the CKKS configuration (CraterLake's 28-bit words, N = 2^16).
+CKKS_TOWERS = (268042241, 265420801)
+
+
+@pytest.mark.parametrize("forward", [True, False], ids=["forward", "inverse"])
+def test_towers_share_one_program_for_v5e(one_chip, forward):
+    """Two moduli at n = 2^16 lower to one program, word for word, and it
+    compiles: each tower's modulus and tables are operands, not constants."""
+    n = 1 << 16
+    lowered = [_entry_program(make_context(q, n), forward, BATCH, one_chip) for q in CKKS_TOWERS]
+    text = [lo.as_text() for lo in lowered]
+    assert text[0] == text[1]
+    compiled = lowered[0].compile()
+    way = "fwd" if forward else "inv"
+    assert _lane_names(compiled, scoped=True) == {f"ntt_tile_{way}", f"ntt_stage_{way}"}
+    assert compiled.memory_analysis().output_size_in_bytes == BATCH * n * 4
+    # the conditional between the tile kernel's stages (`ntt._stage_boundary`)
+    # is built for interpret mode only: neither the compiled program nor the
+    # kernel body traced for it holds one
+    assert "conditional" not in _opcodes(compiled.as_text())
+    ctx = make_context(CKKS_TOWERS[0], n)
+    tabs = ntt.device_tables(ctx, forward, ntt.DEFAULT_TILE)
+    x = jax.ShapeDtypeStruct((BATCH, n), jnp.uint32)
+    body = {
+        interpret: _primitives(
+            jax.make_jaxpr(
+                lambda a, t: ntt._transform(a, t, forward=forward, tile=ntt.DEFAULT_TILE, batch_block=None, interpret=interpret)
+            )(x, tabs).jaxpr
+        )
+        for interpret in (False, True)
+    }
+    assert "pallas_call" in body[False] and "cond" not in body[False]
+    assert "cond" in body[True]

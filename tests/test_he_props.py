@@ -5,6 +5,8 @@ big-integer CRT oracles and (b) obey the timing invariants of the
 tower->bank gang model (speedup bounded by banks, single-bank baseline
 burst-free, phase durations summing below the makespan's span).
 """
+import random
+
 import numpy as np
 
 import repro.he as he
@@ -25,8 +27,8 @@ seeds = st.integers(min_value=0, max_value=2 ** 16)
 @given(n=ns, big_l=towers, seed=seeds)
 def test_crt_roundtrip_and_ct_mul_exact(n, big_l, seed):
     basis = he.make_basis(n, big_l)
-    rng = np.random.default_rng(seed)
-    coeffs = [int(x) for x in rng.integers(0, basis.modulus, n)]
+    rng = random.Random(seed)  # Python ints: the modulus passes int64 at L >= 3
+    coeffs = [rng.randrange(basis.modulus) for _ in range(n)]
     assert basis.decode(basis.encode(coeffs)) == coeffs
     a, b = he.random_ct(basis, seed), he.random_ct(basis, seed + 1)
     assert np.array_equal(he.ct_mul(basis, a, b),

@@ -14,7 +14,6 @@ jax = pytest.importorskip("jax")
 
 from repro.core.ntt import make_context  # noqa: E402
 from repro.kernels import ops, stats  # noqa: E402
-from repro.kernels.ntt import ntt_pallas  # noqa: E402
 
 Q = 114689  # 7 * 2^14 + 1: a modulus no other test uses
 
@@ -92,7 +91,7 @@ def test_counters_count_calls_rows_and_host_time():
 
 def test_a_trace_is_counted_once_per_new_shape():
     ctx = make_context(Q, 512)
-    ntt_pallas.clear_cache()
+    jax.clear_caches()
     x = rand((5, 512))
     for _ in range(3):
         ops.ntt(x, ctx)
@@ -106,7 +105,7 @@ def test_a_trace_is_counted_once_per_new_shape():
 
 
 def test_batch_major_traces_count_small_n_programs():
-    ntt_pallas.clear_cache()
+    jax.clear_caches()
     ops.ntt(rand((2, 4096)), make_context(Q, 4096))  # the slab layout
     assert ops.counters()["ntt"]["traces"] == 1
     assert ops.counters()["ntt"]["batch_major_traces"] == 0
