@@ -16,8 +16,10 @@ The jitted bodies run inside `scope(name)`: a `jax.named_scope` of the same
 carries it in its `op_name`, and one more count of `traces`.  A jitted body
 runs only while JAX traces it, so `traces` counts the times the entry was
 traced anew (a new shape or static argument); in a warm loop it stays put.
-`batch_major_traces` counts those of the traces that built the small-n
-transform's batch-major kernel (`kernels.ntt._batch_major`).
+`batch_major_traces` counts those of the traces that built a whole small-n
+transform (n < 1024, one tile) as the batch-major fused pass
+(`kernels.ntt._fused_pass`), and `batch_major_tile_traces` those that built
+it for a tile of a longer transform or for n >= 1024: every other trace.
 
 A context's tables (its modulus and twiddles, the operands of the compiled
 transform: `kernels.ntt.device_tables`) are built and placed on the device
@@ -46,8 +48,9 @@ PREFIX = "lane."
 SLOW_NS = 50_000_000
 FIELDS = (
     "calls", "rows", "host_ns", "host_ns_max", "over_50ms", "traces", "batch_major_traces", "tables", "table_bytes",
+    "batch_major_tile_traces",
 )
-_CALLS, _ROWS, _NS, _MAX, _SLOW, _TRACES, _BATCH_MAJOR, _TABLES, _TABLE_BYTES = range(len(FIELDS))
+_CALLS, _ROWS, _NS, _MAX, _SLOW, _TRACES, _BATCH_MAJOR, _TABLES, _TABLE_BYTES, _BATCH_MAJOR_TILE = range(len(FIELDS))
 
 _lock = threading.Lock()
 _counts: dict[str, list[int]] = {}
@@ -79,10 +82,12 @@ def scope(name: str):
     return jax.named_scope(PREFIX + name)
 
 
-def batch_major_trace(name: str) -> None:
-    """Counts one program of entry `name` built with the batch-major layout."""
+def batch_major_trace(name: str, tile: bool = False) -> None:
+    """Counts one program of entry `name` whose fused pass was built with the
+    batch-major layout: in `batch_major_tile_traces` with `tile`, else (a
+    whole small-n transform) in `batch_major_traces`."""
     with _lock:
-        _counts.setdefault(name, [0] * len(FIELDS))[_BATCH_MAJOR] += 1
+        _counts.setdefault(name, [0] * len(FIELDS))[_BATCH_MAJOR_TILE if tile else _BATCH_MAJOR] += 1
 
 
 def tables(name: str, q: int, n: int, build):

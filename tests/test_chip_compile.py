@@ -198,3 +198,28 @@ def test_towers_share_one_program_for_v5e(one_chip, forward):
     }
     assert "pallas_call" in body[False] and "cond" not in body[False]
     assert "cond" in body[True]
+
+
+@pytest.mark.parametrize("forward", [True, False], ids=["forward", "inverse"])
+def test_tile_pass_works_on_the_batch_major_input_for_v5e(one_chip, forward):
+    """At 64 x 65536 the fused intra-tile pass takes and returns the (batch, n)
+    array as it lies in HBM, so it needs no relayout of its own, and the
+    kernel body traced for the chip holds no conditional."""
+    n = 1 << 16
+    ctx = make_context(CKKS_TOWERS[0], n)
+    compiled = _entry_program(ctx, forward, BATCH, one_chip).compile()
+    text = compiled.as_text()
+    way = "fwd" if forward else "inv"
+    (line,) = [ln for ln in text.splitlines() if re.match(rf"\s*(?:ROOT )?%ntt_tile_{way}\.\d+ = ", ln)]
+    assert re.search(rf"%ntt_tile_{way}\.\d+ = u32\[{BATCH},{n}\]", line), line[:160]
+    # operands: the scalars, the block's rows, the lane twiddle rows
+    operands = re.findall(r"u32\[[\d,]*\]", line.split("operand_layout_constraints=")[1].split("frontend_attributes")[0])
+    assert operands[1] == f"u32[{BATCH},{n}]", operands
+    tabs = ntt.device_tables(ctx, forward, ntt.DEFAULT_TILE)
+    x = jax.ShapeDtypeStruct((BATCH, n), jnp.uint32)
+    body = _primitives(
+        jax.make_jaxpr(
+            lambda a, t: ntt._transform(a, t, forward=forward, tile=ntt.DEFAULT_TILE, batch_block=None, interpret=False)
+        )(x, tabs).jaxpr
+    )
+    assert "pallas_call" in body and "cond" not in body

@@ -43,6 +43,12 @@ SHAPES = [
     (700, 256, None, None),
     (1, 512, None, None),
     (700, 512, None, 5),
+    # the batch-major fused pass on a tile of a longer transform: a batch
+    # that is not a multiple of the block, an explicit block below 8 rows,
+    # a single row
+    (13, 8192, 1024, None),
+    (5, 16384, 2048, 3),
+    (1, 4096, 512, None),
 ]
 
 

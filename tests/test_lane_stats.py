@@ -118,6 +118,51 @@ def test_batch_major_traces_count_small_n_programs():
     assert (c["intt"]["traces"], c["intt"]["batch_major_traces"]) == (1, 1)
 
 
+def test_batch_major_tile_traces_count_slab_path_programs():
+    jax.clear_caches()
+    ctx = make_context(Q, 4096)
+    for shape in [(2, 4096), (2, 4096), (3, 4096)]:
+        ops.ntt(rand(shape), ctx)
+    ops.ntt(rand((2, 4096)), ctx, tile=1024)  # tiles of a longer transform
+    ops.ntt(rand((2, 512)), make_context(Q, 512), tile=256)  # so below n = 1024
+    ops.intt(rand((2, 4096)), ctx)
+    c = ops.counters()
+    assert (c["ntt"]["traces"], c["ntt"]["batch_major_tile_traces"]) == (4, 4)
+    assert (c["intt"]["traces"], c["intt"]["batch_major_tile_traces"]) == (1, 1)
+    assert c["ntt"]["batch_major_traces"] == c["intt"]["batch_major_traces"] == 0
+
+
+def test_batch_major_tile_traces_stay_0_for_whole_small_transforms():
+    jax.clear_caches()
+    for n in (128, 256, 512):
+        ctx = make_context(Q, n)
+        ops.intt(ops.ntt(rand((3, n)), ctx), ctx)
+    c = ops.counters()
+    for entry in ("ntt", "intt"):
+        assert (c[entry]["traces"], c[entry]["batch_major_traces"]) == (3, 3)
+        assert c[entry]["batch_major_tile_traces"] == 0
+
+
+def test_batch_major_tile_traces_stay_put_in_a_warm_loop_over_towers():
+    from repro.he.rns import rns_primes
+
+    jax.clear_caches()
+    n = 4096
+    ctxs = [make_context(q, n) for q in rns_primes(n, 4, 28)]
+    x = rand((2, n), min(ctx.q for ctx in ctxs))
+    for ctx in ctxs:  # warm: every tower in both directions
+        ops.intt(ops.ntt(x, ctx), ctx)
+    c = ops.counters()
+    assert c["ntt"]["batch_major_tile_traces"] == c["intt"]["batch_major_tile_traces"] == 1
+    ops.reset_counters()
+    for _ in range(2):
+        for ctx in ctxs:
+            ops.intt(ops.ntt(x, ctx), ctx)
+    c = ops.counters()
+    assert c["ntt"]["calls"] == c["intt"]["calls"] == 2 * len(ctxs)
+    assert c["ntt"]["batch_major_tile_traces"] == c["intt"]["batch_major_tile_traces"] == 0
+
+
 def test_slow_calls_are_counted(monkeypatch):
     monkeypatch.setattr(stats, "SLOW_NS", 0)
     ctx = make_context(Q, 256)

@@ -125,6 +125,22 @@ def test_towers_are_exact_against_a_plain_reference(log_n):
     assert (c["ntt"]["batch_major_traces"], c["intt"]["batch_major_traces"]) == ((1, 1) if n < 1024 else (0, 0))
 
 
+def test_the_chip_grouping_of_the_fused_pass_is_exact(monkeypatch):
+    """Interpreted, the fused pass carries groups through up to 2 chunk
+    stages (`ntt.INTERPRET_GROUP_BITS`); compiled for the chip, groups of up
+    to 8 chunks through up to 3 (`ntt.ROWS_GROUP_BITS`).  Here the chip's
+    groups run interpreted: two tiles of 8192 words, so two sweeps, strided
+    groups and a tile offset into the chunk twiddles."""
+    monkeypatch.setattr(ntt, "INTERPRET_GROUP_BITS", ntt.ROWS_GROUP_BITS)
+    n, q = 1 << 14, TOWERS[7]
+    ctx = make_context(q, n)
+    x = rand((3, n), q, 5)
+    f = np.asarray(ops.ntt(x, ctx))
+    np.testing.assert_array_equal(f, forward_ref(x, q))
+    np.testing.assert_array_equal(np.asarray(ops.intt(f, ctx)), x)
+    np.testing.assert_array_equal(np.asarray(ops.intt(x, ctx)), inverse_ref(x, q))
+
+
 def test_one_program_per_direction_for_every_modulus():
     n = 1 << 12
     ctxs = [make_context(q, n) for q in TOWERS[:8]]
