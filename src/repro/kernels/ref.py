@@ -9,8 +9,6 @@ tests/test_core_ntt.py.
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
-
 from repro.core import modmath as mm
 from repro.core import ntt as ntt_core
 from repro.core.ntt import NttContext, make_context  # re-export
@@ -34,8 +32,3 @@ def modmul_ref(a, b, ctx: NttContext):
 def polymul_ref(a, b, ctx: NttContext):
     """Negacyclic polynomial product over the last axis (eq. 1)."""
     return ntt_core.polymul_negacyclic_jnp(a, b, ctx)
-
-
-def ntt_conv_ref(u, kern, ctx: NttContext):
-    """Negacyclic convolution of integer sequences (u, kern in [0, q))."""
-    return polymul_ref(jnp.asarray(u, jnp.uint32), jnp.asarray(kern, jnp.uint32), ctx)

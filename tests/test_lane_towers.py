@@ -125,20 +125,32 @@ def test_towers_are_exact_against_a_plain_reference(log_n):
     assert (c["ntt"]["batch_major_traces"], c["intt"]["batch_major_traces"]) == ((1, 1) if n < 1024 else (0, 0))
 
 
-def test_the_chip_grouping_of_the_fused_pass_is_exact(monkeypatch):
+#: (n, tile) where the chip's plan of the fused pass differs from the one it
+#: runs interpreted: a tile of 3, 5 or 6 chunk stages (4 split two and two
+#: either way); None is the default tile
+CHIP_PLANS = [
+    (1 << 10, None), (1 << 12, None), (1 << 13, None), (1 << 14, None),
+    (1 << 11, 1 << 10), (1 << 12, 1 << 10), (1 << 13, 1 << 10), (1 << 14, 1 << 12),
+]
+
+
+@pytest.mark.parametrize("direction", ["forward", "inverse"])
+@pytest.mark.parametrize("n,tile", CHIP_PLANS)
+def test_chip_plan_is_exact(monkeypatch, n, tile, direction):
     """Interpreted, the fused pass carries groups through up to 2 chunk
     stages (`ntt.INTERPRET_GROUP_BITS`); compiled for the chip, groups of up
     to 8 chunks through up to 3 (`ntt.ROWS_GROUP_BITS`).  Here the chip's
-    groups run interpreted: two tiles of 8192 words, so two sweeps, strided
-    groups and a tile offset into the chunk twiddles."""
+    plan runs interpreted: its sweeps, its strided groups and, with more than
+    one tile, its tile offset into the chunk twiddles."""
+    forward = direction == "forward"
+    words = min(tile or ntt.DEFAULT_TILE, n)  # of a tile
+    assert ntt._rows_plan(n, words, forward, ntt.ROWS_GROUP_BITS) != ntt._rows_plan(n, words, forward, ntt.INTERPRET_GROUP_BITS)
     monkeypatch.setattr(ntt, "INTERPRET_GROUP_BITS", ntt.ROWS_GROUP_BITS)
-    n, q = 1 << 14, TOWERS[7]
+    q = TOWERS[7]
     ctx = make_context(q, n)
-    x = rand((3, n), q, 5)
-    f = np.asarray(ops.ntt(x, ctx))
-    np.testing.assert_array_equal(f, forward_ref(x, q))
-    np.testing.assert_array_equal(np.asarray(ops.intt(f, ctx)), x)
-    np.testing.assert_array_equal(np.asarray(ops.intt(x, ctx)), inverse_ref(x, q))
+    x = rand((3, n), q, n)
+    got = np.asarray((ops.ntt if forward else ops.intt)(x, ctx, tile=tile))
+    np.testing.assert_array_equal(got, (forward_ref if forward else inverse_ref)(x, q))
 
 
 def test_one_program_per_direction_for_every_modulus():
